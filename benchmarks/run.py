@@ -17,6 +17,7 @@ import sys
 import traceback
 
 from benchmarks.common import Report
+from repro.launch.compile_cache import enable_compile_cache
 
 CORE = [
     "fig7_convergence",
@@ -81,6 +82,7 @@ def main(argv=None) -> None:
                     help="also write the report (rows + per-row metrics "
                          "dicts + run context) as JSON to PATH")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     report = Report()
     failures = 0

@@ -84,7 +84,10 @@ class TPSTry:
             raise ValueError(f"query {q.to_text()} expands to no strings <= {self.max_len}")
         self._queries[qh] = q
         self._strings[qh] = strings
-        for s in strings:
+        # sorted: a frozenset of strings iterates in an order that varies
+        # with each process's string hashing, and node ids follow insertion
+        # order — the compiled field (and its persistent-cache key) must not
+        for s in sorted(strings):
             cur = 0
             for sym in s:
                 node = self.nodes[cur]

@@ -288,6 +288,24 @@ def _capped_transition(trie: TrieArrays, depth_cap: int) -> np.ndarray:
     return T
 
 
+@partial(jax.jit, static_argnames=("n", "n_blocks_out", "block_n", "block_e"))
+def _pallas_depth_step(beta_t, T, Tsum, src, dst_lab, inv_cnt_edge,
+                       packed_src, dst_local, dst_label, inv_local, meta_t,
+                       *, n: int, n_blocks_out: int, block_n: int,
+                       block_e: int):
+    """One depth of the ``pallas`` field, feature-major (``beta_t`` is
+    ``(N, n)``): the step's per-edge mass over ALL edges, and the next delta
+    state over local edges through the ``vm_step`` kernel."""
+    from repro.kernels.vm_step.kernel import vm_step_packed
+
+    mass = (jnp.take(beta_t, src, axis=1)
+            * jnp.take(Tsum.T, dst_lab, axis=1)).sum(axis=0) * inv_cnt_edge
+    out_t = vm_step_packed(
+        jnp.take(beta_t, packed_src, axis=1), T, dst_local, dst_label,
+        inv_local, meta_t, n_blocks_out, block_n, block_e)
+    return out_t[:, :n], mass
+
+
 def _pallas_field(
     g: LabelledGraph,
     trie: TrieArrays,
@@ -296,7 +314,6 @@ def _pallas_field(
     depth_cap: int,
     pre: Dict,
     dense_ext_to: bool,
-    interpret: Optional[bool] = None,
 ):
     """Pallas-backed extroversion field: the depth-advancing DP step runs as
     the ``vm_step`` TPU kernel over the graph's cached edge packing.
@@ -309,16 +326,11 @@ def _pallas_field(
         alpha  = sum_d beta_d
         mass  += rowsum over children of the beta_{d-1} messages (ALL edges)
 
+    The states are kept feature-major (``(N, n)``), the kernel's layout.
     The packing (src/dst/label/1-cnt channels) is partition-independent and
     cached on the graph; per iteration only the partition vector and the
-    derived local-edge mask move to the device.  ``interpret`` defaults to
-    auto: off when running on a real TPU, on elsewhere.
+    derived local-edge mask move to the device.
     """
-    from repro.kernels.vm_step.ops import vm_step
-
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-
     n, m = g.n, g.m
     N = trie.n_nodes
     cnt = pre.get("cnt")
@@ -339,6 +351,8 @@ def _pallas_field(
             np.asarray(cnt)[g.src, g.labels[g.dst]], 1.0)
         pdev = {
             "packed_src": jnp.asarray(packed.src),
+            "dst_local": jnp.asarray(packed.dst_local),
+            "meta_t": jnp.asarray(packed.meta.T),
             "dst_global": jnp.asarray(dst_global),
             "inv_cnt_edge": jnp.asarray(inv_cnt_edge.astype(np.float32)),
         }
@@ -361,21 +375,22 @@ def _pallas_field(
                     == part_dev[pdev["dst_global"]]).astype(jnp.float32)
     inv_local = inv_cnt_packed * local_packed  # 0 on padding (inv_cnt is 0)
     dst_lab = vlabels[dst]
-    inv_cnt_edge = pdev["inv_cnt_edge"]
 
     # depth-1 priors — same device arithmetic as the jnp backend
-    alpha = _prior_columns(trie.depth, trie.label, N, vlabels,
-                           dev["lab_vcount"], jnp.asarray(trie.p), n)
-    beta = alpha
+    alpha_t = _prior_columns(trie.depth, trie.label, N, vlabels,
+                             dev["lab_vcount"], jnp.asarray(trie.p), n).T
+    beta_t = alpha_t
     mass = jnp.zeros((m,), dtype=jnp.float32)
     max_depth = min(trie.max_depth, depth_cap)
     for _ in range(2, max_depth + 1):
-        # per-edge mass of the depth step over ALL edges (cut + local)
-        mass = mass + (beta[src] * Tsum[dst_lab]).sum(axis=1) * inv_cnt_edge
-        # the DP itself advances over local edges only — vm_step kernel
-        beta = vm_step(beta, T, packed, dst_label, inv_local, n,
-                       interpret=interpret, use_pallas=True)
-        alpha = alpha + beta
+        beta_t, step_mass = _pallas_depth_step(
+            beta_t, T, Tsum, src, dst_lab, pdev["inv_cnt_edge"],
+            pdev["packed_src"], pdev["dst_local"], dst_label, inv_local,
+            pdev["meta_t"], n=n, n_blocks_out=packed.n_blocks_out,
+            block_n=packed.block_n, block_e=packed.block_e)
+        mass = mass + step_mass
+        alpha_t = alpha_t + beta_t
+    alpha = alpha_t.T
 
     counted = [
         i for i in range(N)
@@ -387,9 +402,8 @@ def _pallas_field(
 
 def _build_sharded_fn(mesh, trie: TrieArrays, depth_cap: int,
                       bps: int, block_n: int, block_e: int,
-                      n_local_pad: int, h_pad: int, interpret: bool,
-                      exchange: str = "psum", n_shards: int = 1,
-                      round_cap: Tuple[int, ...] = ()):
+                      n_local_pad: int, exchange: str = "psum",
+                      n_shards: int = 1, round_cap: Tuple[int, ...] = ()):
     """shard_map'd halo-exchange depth loop (see module docstring §sharded).
 
     Static per (mesh, trie topology, packing shapes, exchange backend): the
@@ -402,8 +416,10 @@ def _build_sharded_fn(mesh, trie: TrieArrays, depth_cap: int,
     ``ppermute`` rounds of the cold per-shard-pair slices (``send`` = the
     ``send_local`` tables, round ``r`` padded to the static
     ``round_cap[r]``; ``src_map`` is then the packing's sliced variant).
+    Each shard keeps its states feature-major (``(N, n_local_pad)``), the
+    ``vm_step`` kernel's layout, so the exchanged rows of the module
+    docstring travel as columns.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.kernels.vm_step.kernel import vm_step_packed
@@ -423,9 +439,10 @@ def _build_sharded_fn(mesh, trie: TrieArrays, depth_cap: int,
                            src_g, dst_g, vlab, fr_a, fr_b, send))
         local = (part[src_g] == part[dst_g]).astype(jnp.float32)
         inv_local = inv_full * local
-        alpha = _prior_columns(depth, labels_n, N, vlab, lab_vcount, p,
-                               n_local_pad)
-        beta = alpha
+        tsum_t = Tsum.T
+        alpha_t = _prior_columns(depth, labels_n, N, vlab, lab_vcount, p,
+                                 n_local_pad).T
+        beta_t = alpha_t
         slot_mass = jnp.zeros(inv_full.shape, dtype=jnp.float32)
         for _ in range(2, max_depth + 1):
             if sliced:
@@ -433,41 +450,43 @@ def _build_sharded_fn(mesh, trie: TrieArrays, depth_cap: int,
                 # then ring-exchange the cold per-pair slices — round r
                 # ships each shard's slice for the reader r hops ahead,
                 # padded to that round's own largest pair
-                hot = jax.lax.psum(beta[fr_a] * fr_b[:, None], "model")
+                hot = jax.lax.psum(beta_t[:, fr_a] * fr_b[None, :], "model")
                 me = jax.lax.axis_index("model")
                 parts = [hot]
                 for r in range(1, n_shards):
                     reader = jax.lax.rem(me + r, n_shards)
                     rows = jax.lax.dynamic_index_in_dim(
                         send, reader, axis=0, keepdims=False)
-                    payload = beta[rows[: round_cap[r]]]
+                    payload = beta_t[:, rows[: round_cap[r]]]
                     parts.append(jax.lax.ppermute(
                         payload, "model",
                         perm=[(i, (i + r) % n_shards)
                               for i in range(n_shards)]))
-                fr = jnp.concatenate(parts, axis=0)
+                fr = jnp.concatenate(parts, axis=1)
             else:
                 # union exchange: each shard contributes its owned frontier
                 # rows (fr_a = fr_local_idx, fr_b = fr_owned); psum
                 # completes the union (each row has exactly one owner)
-                fr = jax.lax.psum(beta[fr_a] * fr_b[:, None], "model")
-            a_in = jnp.concatenate([beta, fr], axis=0)
+                fr = jax.lax.psum(beta_t[:, fr_a] * fr_b[None, :], "model")
+            a_src_t = jnp.take(jnp.concatenate([beta_t, fr], axis=1),
+                               src_map, axis=1)
             # per-slot mass over ALL edges (cut + local) at this depth
             slot_mass = slot_mass + (
-                a_in[src_map] * Tsum[dst_label]).sum(axis=1) * inv_full
+                a_src_t * jnp.take(tsum_t, dst_label, axis=1)
+            ).sum(axis=0) * inv_full
             # the DP advances over intra-partition edges only
-            beta = vm_step_packed(
-                a_in, T, src_map, dst_local, dst_label, inv_local, meta,
-                bps, block_n, block_e, interpret=interpret)
-            alpha = alpha + beta
-        return alpha[None], slot_mass[None]
+            beta_t = vm_step_packed(
+                a_src_t, T, dst_local, dst_label, inv_local, meta.T,
+                bps, block_n, block_e)
+            alpha_t = alpha_t + beta_t
+        return alpha_t.T[None], slot_mass[None]
 
     sharded = (P("model"),) * 11
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=sharded + (P(), P(), P(), P(), P()),
         out_specs=(P("model"), P("model")),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -531,7 +550,6 @@ def _pallas_sharded_field(
     depth_cap: int,
     pre: Dict,
     dense_ext_to: bool,
-    interpret: Optional[bool] = None,
     mesh=None,
     shard_map_source: str = "stripe",
     halo_exchange: str = "sliced",
@@ -555,8 +573,6 @@ def _pallas_sharded_field(
     """
     from repro.graphs.sharded_packing import compute_shard_order
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     if mesh is None:
         mesh = pre.get("_mesh")
     if mesh is None:
@@ -602,13 +618,13 @@ def _pallas_sharded_field(
     key = ("sharded", trie.topology_signature(), int(depth_cap), S,
            sp.blocks_per_shard, sp.block_n, sp.block_e, sp.eb_cap,
            sp.n_local_pad, sp.h_pad, sp.hot_pad, round_cap, halo_exchange,
-           bool(interpret), id(mesh))
+           id(mesh))
     fn = _FIELD_CACHE.get(key)
     if fn is None:
         fn = _build_sharded_fn(
             mesh, trie, depth_cap, sp.blocks_per_shard, sp.block_n,
-            sp.block_e, sp.n_local_pad, sp.h_pad, interpret,
-            exchange=halo_exchange, n_shards=S, round_cap=round_cap)
+            sp.block_e, sp.n_local_pad, exchange=halo_exchange,
+            n_shards=S, round_cap=round_cap)
         while len(_FIELD_CACHE) >= 64:
             _FIELD_CACHE.pop(next(iter(_FIELD_CACHE)))
         _FIELD_CACHE[key] = fn
@@ -645,6 +661,9 @@ def _pallas_sharded_field(
         "shard_map_source": token.split(":")[0],
         "halo_exchange": halo_exchange,
         "n_shards": S,
+        # devices the kernel's output actually spans (a mesh of one device
+        # repeated, or inputs pinned to one chip, would show here)
+        "n_devices": len(alpha_sh.sharding.device_set),
         "n_frontier": sp.n_frontier,
         "hot_rows": sp.hot_pad,
         "sliced_rows": sp.hot_pad + int(sp.round_cap[1:].sum()),

@@ -411,7 +411,12 @@ def _fill_shard(sp: ShardedVMPacking, s: int, g, cnt,
     if int(eb_need.sum()) > sp.eb_cap:
         return None
 
-    sp.meta[s] = 0                      # pad rows: block 0, is_first=0
+    # pad rows extend the last block's run (is_first=0, zero inv_cnt): the
+    # TPU kernel keeps an output block in VMEM only while consecutive edge
+    # blocks hit it, so a pad row pointing back to block 0 would write a
+    # stale buffer over block 0's finished rows
+    sp.meta[s, :, 0] = bps - 1
+    sp.meta[s, :, 1] = 0
     sp.src_global[s] = 0
     sp.dst_local[s] = 0
     sp.dst_global[s] = 0

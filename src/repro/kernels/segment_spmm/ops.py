@@ -37,26 +37,30 @@ def pack_edges(edge_src: np.ndarray, edge_dst: np.ndarray, n: int,
     order = np.argsort(edge_dst, kind="stable")
     src_s, dst_s = edge_src[order], edge_dst[order]
     n_blocks_out = (n + block_n - 1) // block_n
-    blk = dst_s // block_n
+    blk = (dst_s // block_n).astype(np.int64)
 
-    src_chunks, dstloc_chunks, mask_chunks, meta = [], [], [], []
-    for b in range(n_blocks_out):
-        sel = blk == b
-        cnt = int(sel.sum())
-        n_eb = max(1, (cnt + block_e - 1) // block_e)
-        pad = n_eb * block_e - cnt
-        src_chunks.append(np.concatenate([src_s[sel], np.zeros(pad, src_s.dtype)]))
-        dstloc_chunks.append(np.concatenate(
-            [dst_s[sel] - b * block_n, np.zeros(pad, dst_s.dtype)]))
-        mask_chunks.append(np.concatenate(
-            [np.ones(cnt, bool), np.zeros(pad, bool)]))
-        for j in range(n_eb):
-            meta.append((b, 1 if j == 0 else 0))
+    # each destination block's edges fill its own run of edge blocks (at
+    # least one), padded to a multiple of block_e
+    cnt = np.bincount(blk, minlength=n_blocks_out)
+    n_eb = np.maximum(1, -(-cnt // block_e))
+    slot0 = np.concatenate([[0], np.cumsum(n_eb)]) * block_e
+    edge0 = np.concatenate([[0], np.cumsum(cnt)])
+    slot = slot0[blk] + np.arange(blk.size) - edge0[blk]
+    e_pad = int(slot0[-1])
+    src = np.zeros(e_pad, np.int32)
+    dst_local = np.zeros(e_pad, np.int32)
+    pad_mask = np.zeros(e_pad, bool)
+    src[slot] = src_s
+    dst_local[slot] = dst_s - blk * block_n
+    pad_mask[slot] = True
+    meta = np.zeros((int(n_eb.sum()), 2), np.int32)
+    meta[:, 0] = np.repeat(np.arange(n_blocks_out), n_eb)
+    meta[slot0[:-1] // block_e, 1] = 1
     return PackedEdges(
-        src=np.concatenate(src_chunks).astype(np.int32),
-        dst_local=np.concatenate(dstloc_chunks).astype(np.int32),
-        meta=np.asarray(meta, np.int32),
-        pad_mask=np.concatenate(mask_chunks),
+        src=src,
+        dst_local=dst_local,
+        meta=meta,
+        pad_mask=pad_mask,
         order=order,
         n_blocks_out=n_blocks_out,
         block_n=block_n,

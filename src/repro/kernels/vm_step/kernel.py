@@ -3,15 +3,35 @@
 The paper's Alg. 1 hot loop, reformulated (DESIGN.md §2) as a label-masked
 SpMM.  Same packing contract as segment_spmm (edges sorted by destination,
 one destination block per edge block, scalar-prefetched output index), with
-the per-edge trie transition fused in:
+the per-edge trie transition fused in.  The kernel works feature-major: the
+trie-state axis N leads and edges / vertices run along the lanes.
 
-    per edge block: A   = alpha[src]              gather   (block_e, N)
-                    M   = A x T[label(dst)]       batched tiny matmul
-                    out += onehot(dst_local)^T M  MXU scatter
+    in XLA, before the kernel:  At = alphaT[:, src]               (N, E_pad)
+    per edge block:             Mt = sum_l [label(dst) == l] * (T[l]^T @ At)
+                                Mt *= 1 / cnt
+                                outT += Mt @ onehot(dst_local)     MXU scatter
 
-The trie transition tensor T (L x N x N, ~ 12x24x24 floats) lives wholly in
-VMEM — the intensional workload summary is small by construction (paper §4),
-which is what makes this kernel VMEM-friendly at any graph size.
+Nothing is gathered inside the kernel: the TPU compiler refuses vector
+integer indexing of a VMEM ref, so the source columns arrive pre-gathered
+as ``(N, block_e)`` blocks, and the destination-label transition is a
+static loop over the L labels, each step one 2-D matmul masked by
+``dst_label == l``.  The trie transition tensor T (L x N x N, ~ 12x24x24
+floats) lives wholly in VMEM — the intensional workload summary is small by
+construction (paper §4).
+
+Layouts that tile on the chip without padding copies:
+
+* ``(N, E_pad)`` source columns: N (~24) rows pad to a multiple of 8, where
+  an ``(E_pad, N)`` operand would pad N to 128 lanes (5x the bytes);
+* per-edge channels (``dst_local``, ``dst_label``, ``inv_cnt``) as
+  ``(1, E_pad)`` rows in ``(1, block_e)`` blocks.  1-D blocks are laid out
+  ``T(1024)`` by XLA but ``T(256)`` by Mosaic and are refused, and
+  ``(E_pad, 1)`` columns cost a relayout copy 128x their size;
+* the scalar-prefetched block table ``meta`` as ``(2, EB)`` (row 0: output
+  block, row 1: first-edge-block flag).  SMEM pads the minor dimension of a
+  2-D array to 128 words, so ``(EB, 2)`` would cost 64x its size; ``(2,
+  EB)`` costs ``8 * EB`` bytes, which the 1 MiB of SMEM bounds at
+  :data:`MAX_EDGE_BLOCKS`.
 """
 from __future__ import annotations
 
@@ -22,67 +42,85 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+#: edge blocks one call can take on a TPU v5e: the ``(2, EB)`` int32 block
+#: table plus Mosaic's own scalars (~1 KiB of spill slots) must fit the
+#: 1 MiB of SMEM; this leaves them 8 KiB.  A 1M-vertex, degree-6 graph
+#: needs ~23K blocks of 256 edges; at 10M vertices the grid would have to
+#: be split across calls.
+MAX_EDGE_BLOCKS = ((1 << 20) - (8 << 10)) // 8
 
-def _vm_kernel(meta_ref, src_ref, dstloc_ref, dstlab_ref, invcnt_ref,
-               alpha_ref, T_ref, o_ref, *, block_n: int, block_e: int):
+
+def default_interpret() -> bool:
+    """Interpret-mode policy for the TAPER kernels: compiled by Mosaic on a
+    TPU backend, run by the Pallas interpreter everywhere else."""
+    return jax.default_backend() != "tpu"
+
+
+def _vm_kernel(meta_ref, a_ref, dstloc_ref, dstlab_ref, invcnt_ref, Tt_ref,
+               o_ref, *, block_n: int, n_labels: int):
     e_i = pl.program_id(0)
-    is_first = meta_ref[e_i, 1]
 
-    @pl.when(is_first == 1)
+    @pl.when(meta_ref[1, e_i] == 1)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    src = src_ref[...]                        # (block_e,)
-    dst_loc = dstloc_ref[...]
-    dst_lab = dstlab_ref[...]
-    inv_cnt = invcnt_ref[...]                 # 0 on padded edges
-
-    A = alpha_ref[src]                        # (block_e, N)
-    Tsel = T_ref[dst_lab]                     # (block_e, N, N)
-    M = jnp.einsum("en,enm->em", A, Tsel,
-                   preferred_element_type=jnp.float32)
-    M = M * inv_cnt[:, None]
-    onehot = (dst_loc[None, :] == jax.lax.iota(jnp.int32, block_n)[:, None])
+    At = a_ref[...]                           # (N, block_e)
+    dst_lab = dstlab_ref[...]                 # (1, block_e)
+    Mt = jnp.zeros(At.shape, jnp.float32)
+    for l in range(n_labels):
+        step = jnp.dot(Tt_ref[l], At, preferred_element_type=jnp.float32,
+                       precision=jax.lax.Precision.HIGHEST)
+        Mt = Mt + jnp.where(dst_lab == l, step, 0.0)
+    Mt = Mt * invcnt_ref[...]                 # 0 on padded edges
+    dst_loc = dstloc_ref[...]                 # (1, block_e)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (block_n, dst_loc.shape[1]), 0)
+    onehot = (rows == dst_loc).astype(jnp.float32)   # (block_n, block_e)
     contrib = jax.lax.dot_general(
-        onehot.astype(M.dtype), M, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        Mt, onehot, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)  # (N, block_n)
     o_ref[...] += contrib.astype(o_ref.dtype)
 
 
 def vm_step_packed(
-    alpha: jnp.ndarray,        # (n, N)
+    a_src_t: jnp.ndarray,      # (N, E_pad) alphaT[:, src], gathered by caller
     T: jnp.ndarray,            # (L, N, N)
-    src: jnp.ndarray,          # (E_pad,)
     dst_local: jnp.ndarray,    # (E_pad,)
     dst_label: jnp.ndarray,    # (E_pad,)
     inv_cnt: jnp.ndarray,      # (E_pad,) 0 on padding
-    meta: jnp.ndarray,         # (EB, 2)
+    meta: jnp.ndarray,         # (2, EB) [dst_block_id; is_first]
     n_blocks_out: int,
     block_n: int,
     block_e: int,
-    interpret: bool = True,
 ) -> jnp.ndarray:
-    E_pad = src.shape[0]
-    n, N = alpha.shape
+    """One DP step over packed edge blocks, feature-major: returns the
+    ``(N, n_blocks_out * block_n)`` transposed next state.  Interpret mode
+    follows :func:`default_interpret`."""
+    N, E_pad = a_src_t.shape
     L = T.shape[0]
     EB = E_pad // block_e
-    kernel = functools.partial(_vm_kernel, block_n=block_n, block_e=block_e)
+    if meta.shape != (2, EB):
+        raise ValueError(f"meta must be (2, {EB}), got {meta.shape}")
+    row = lambda x: jnp.reshape(x, (1, E_pad))
+    kernel = functools.partial(_vm_kernel, block_n=block_n, n_labels=L)
+    edge_spec = pl.BlockSpec((1, block_e), lambda e, meta: (0, e))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(EB,),
         in_specs=[
-            pl.BlockSpec((block_e,), lambda e, meta: (e,)),
-            pl.BlockSpec((block_e,), lambda e, meta: (e,)),
-            pl.BlockSpec((block_e,), lambda e, meta: (e,)),
-            pl.BlockSpec((block_e,), lambda e, meta: (e,)),
-            pl.BlockSpec((n, N), lambda e, meta: (0, 0)),
+            pl.BlockSpec((N, block_e), lambda e, meta: (0, e)),
+            edge_spec,
+            edge_spec,
+            edge_spec,
             pl.BlockSpec((L, N, N), lambda e, meta: (0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((block_n, N), lambda e, meta: (meta[e, 0], 0)),
+        out_specs=pl.BlockSpec((N, block_n), lambda e, meta: (0, meta[0, e])),
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_blocks_out * block_n, N), alpha.dtype),
-        interpret=interpret,
-    )(meta, src, dst_local, dst_label, inv_cnt, alpha, T)
+        out_shape=jax.ShapeDtypeStruct((N, n_blocks_out * block_n),
+                                       a_src_t.dtype),
+        interpret=default_interpret(),
+    )(meta, a_src_t, row(dst_local), row(dst_label), row(inv_cnt),
+      jnp.swapaxes(T, 1, 2))

@@ -1,15 +1,11 @@
 """Wrapper: reuses segment_spmm's edge packing; adds label/inv-cnt channels."""
 from __future__ import annotations
 
-from typing import Tuple
-
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.segment_spmm.ops import PackedEdges, pack_edges
 from repro.kernels.vm_step.kernel import vm_step_packed
-from repro.kernels.vm_step.ref import vm_step_reference
 
 
 def pack_vm_inputs(edge_src, edge_dst, labels, cnt, n: int,
@@ -36,21 +32,12 @@ def vm_step(
     dst_label: jnp.ndarray,
     inv_cnt: jnp.ndarray,
     n: int,
-    interpret: bool = True,
-    use_pallas: bool = True,
 ) -> jnp.ndarray:
-    if not use_pallas:
-        dst_block = np.repeat(packed.meta[:, 0], packed.block_e)
-        dst_global = jnp.asarray(dst_block * packed.block_n) + jnp.asarray(
-            packed.dst_local)
-        return vm_step_reference(
-            alpha, T, jnp.asarray(packed.src), dst_global, inv_cnt,
-            dst_label, n)
-    out = vm_step_packed(
-        alpha, T,
-        jnp.asarray(packed.src), jnp.asarray(packed.dst_local),
-        dst_label, inv_cnt, jnp.asarray(packed.meta),
-        packed.n_blocks_out, packed.block_n, packed.block_e,
-        interpret=interpret,
-    )
-    return out[:n]
+    """One DP step ``(n, N) -> (n, N)`` over a :func:`pack_vm_inputs`
+    packing: gathers the source rows in XLA and runs the kernel on them."""
+    a_src_t = jnp.take(alpha.T, jnp.asarray(packed.src), axis=1)
+    out_t = vm_step_packed(
+        a_src_t, T, jnp.asarray(packed.dst_local), dst_label, inv_cnt,
+        jnp.asarray(packed.meta.T), packed.n_blocks_out, packed.block_n,
+        packed.block_e)
+    return out_t[:, :n].T

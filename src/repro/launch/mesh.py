@@ -18,10 +18,13 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_smoke_mesh(n_devices: int | None = None):
-    """Tiny mesh over however many (host) devices exist — used by sharding
-    unit tests, which run with the default single CPU device."""
+    """``(1, n)`` mesh over however many devices exist — the sharded
+    field's default mesh.  Its axes are ``Auto``: the field post-processes
+    the ``shard_map`` outputs with plain indexing, which an ``Explicit``
+    axis (``jax.make_mesh``'s default) refuses to resolve."""
     n = n_devices or len(jax.devices())
-    return jax.make_mesh((1, n), ("data", "model"))
+    auto = jax.sharding.AxisType.Auto
+    return jax.make_mesh((1, n), ("data", "model"), axis_types=(auto, auto))
 
 
 def chips_in(mesh) -> int:

@@ -9,6 +9,7 @@ import argparse
 from repro.core.rpq import parse_rpq
 from repro.graphs.generators import musicbrainz_like, provgen_like
 from repro.graphs.partition import hash_partition
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve.engine import GraphQueryEngine, ServeConfig
 from repro.utils import get_logger
 from repro.workload.stream import WorkloadStream
@@ -33,6 +34,7 @@ def main() -> None:
     ap.add_argument("--ticks", type=int, default=10)
     ap.add_argument("--batch", type=int, default=100)
     args = ap.parse_args()
+    enable_compile_cache()
 
     g = (provgen_like if args.dataset == "provgen" else musicbrainz_like)(
         args.n, seed=3)

@@ -29,7 +29,6 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import MoEConfig
@@ -218,11 +217,11 @@ def apply_sharded(params, x: jnp.ndarray, cfg: MoEConfig, mesh, rules,
         return out, aux_loss[None], z_loss[None], dropped[None]
 
     shard_spec = P(batch_axes)
-    out, aux_loss, z_loss, dropped = shard_map(
+    out, aux_loss, z_loss, dropped = jax.shard_map(
         local_moe, mesh=mesh,
         in_specs=(x_spec, router_spec, ew_spec, ew_spec, ew_spec_out),
         out_specs=(x_spec, shard_spec, shard_spec, shard_spec),
-        check_rep=False,
+        check_vma=False,
     )(x, params["router"]["w"], params["gate"], params["up"], params["down"])
     aux_loss, z_loss, dropped = (aux_loss.mean(), z_loss.mean(), dropped.mean())
 

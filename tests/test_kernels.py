@@ -105,6 +105,45 @@ def test_segment_spmm_sweep(n, e, f, block_n, block_e, seed):
                                rtol=1e-4, atol=1e-4)
 
 
+def _pack_edges_loop(edge_src, edge_dst, n, block_n, block_e):
+    """Per-destination-block loop transcription of ``pack_edges``: the
+    reference its vectorised form must reproduce exactly."""
+    order = np.argsort(edge_dst, kind="stable")
+    src_s, dst_s = edge_src[order], edge_dst[order]
+    blk = dst_s // block_n
+    src, dloc, mask, meta = [], [], [], []
+    for b in range(-(-n // block_n)):
+        sel = blk == b
+        cnt = int(sel.sum())
+        n_eb = max(1, -(-cnt // block_e))
+        pad = n_eb * block_e - cnt
+        src.append(np.concatenate([src_s[sel], np.zeros(pad, src_s.dtype)]))
+        dloc.append(np.concatenate(
+            [dst_s[sel] - b * block_n, np.zeros(pad, dst_s.dtype)]))
+        mask.append(np.concatenate([np.ones(cnt, bool), np.zeros(pad, bool)]))
+        meta += [(b, int(j == 0)) for j in range(n_eb)]
+    return (np.concatenate(src).astype(np.int32),
+            np.concatenate(dloc).astype(np.int32),
+            np.asarray(meta, np.int32), np.concatenate(mask), order)
+
+
+@pytest.mark.parametrize("n,e,block_n,block_e", [
+    (1, 0, 8, 8), (97, 5, 32, 64), (500, 2999, 128, 256), (300, 1024, 8, 8),
+])
+def test_pack_edges_matches_loop_reference(n, e, block_n, block_e):
+    rng = np.random.default_rng(n + e)
+    src = rng.integers(0, n, e)
+    dst = rng.integers(0, n, e)
+    packed = pack_edges(src, dst, n, block_n, block_e)
+    ref = _pack_edges_loop(src, dst, n, block_n, block_e)
+    got = (packed.src, packed.dst_local, packed.meta, packed.pad_mask,
+           packed.order)
+    for a, b in zip(got, ref):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(a, b)
+    assert packed.n_blocks_out == -(-n // block_n)
+
+
 def test_segment_spmm_fallback_matches():
     rng = np.random.default_rng(1)
     n, e, f = 100, 400, 16
@@ -160,6 +199,81 @@ def test_vm_step_sweep(n, e, n_labels, seed):
                             jnp.asarray(labels[dst]), n)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("n,e,n_labels,block_n,block_e", [
+    (300, 1000, 3, 64, 256),    # 1000 edges: no multiple of the edge block
+    (257, 777, 12, 128, 256),   # L = 12: twelve masked label steps
+    (90, 3, 12, 32, 128),       # most destination blocks are all padding
+])
+def test_vm_step_gather_free_parity(n, e, n_labels, block_n, block_e):
+    """The gather-free kernel (sources gathered in XLA, one masked 2-D dot
+    per label, feature-major blocks) against the jnp oracle."""
+    rng = np.random.default_rng(n * 7919 + e)
+    trie = _random_trie(rng, n_labels)
+    src = rng.integers(0, n, e).astype(np.int32)
+    dst = rng.integers(0, n, e).astype(np.int32)
+    labels = rng.integers(0, n_labels, n).astype(np.int32)
+    cnt = rng.integers(1, 5, (n, n_labels)).astype(np.int32)
+    alpha = jnp.asarray(rng.random((n, trie.n_nodes)).astype(np.float32))
+    T = jnp.asarray(build_transition(trie.parent, trie.label, trie.cond_p,
+                                     n_labels))
+    packed, dst_label, inv_cnt = pack_vm_inputs(
+        src, dst, labels, cnt, n, block_n=block_n, block_e=block_e)
+    assert packed.src.shape[0] % block_e == 0 and e % block_e != 0
+    out = vm_step(alpha, T, packed, dst_label, inv_cnt, n)
+    inv_ref = 1.0 / np.maximum(cnt[src, labels[dst]], 1.0)
+    ref = vm_step_reference(alpha, T, jnp.asarray(src), jnp.asarray(dst),
+                            jnp.asarray(inv_ref.astype(np.float32)),
+                            jnp.asarray(labels[dst]), n)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-5, atol=1e-6)
+
+
+_SHARDED_PARITY = """
+import numpy as np
+from repro.core.rpq import parse_rpq
+from repro.core.tpstry import TPSTry
+from repro.core.visitor import extroversion_field
+from repro.graphs.generators import power_law_labelled
+from repro.graphs.partition import hash_partition
+
+g = power_law_labelled(700, n_labels=12, seed=3)
+arrays = TPSTry.from_workload(
+    [(parse_rpq("L0.L1.(L2|L3).L1"), 0.6), (parse_rpq("L4.L5.L0"), 0.4)]
+).compile(g.label_names)
+part = hash_partition(g.n, 4, seed=0)
+ref = extroversion_field(g, arrays, part, 4, backend="jnp")
+for source in ("stripe", "partition"):
+    pre = {}
+    sh = extroversion_field(g, arrays, part, 4, _precomputed=pre,
+                            backend="pallas_sharded",
+                            shard_map_source=source, halo_exchange="sliced")
+    assert pre["_halo_stats"]["n_devices"] == 4, pre["_halo_stats"]
+    for f in ("alpha", "edge_mass", "extroversion", "ext_to"):
+        np.testing.assert_allclose(getattr(sh, f), getattr(ref, f),
+                                   rtol=1e-4, atol=2e-6, err_msg=f)
+print("sharded parity ok")
+"""
+
+
+def test_vm_step_sharded_parity_on_forced_host_devices():
+    """The kernel under ``shard_map`` on four forced host devices (this
+    process has one CPU device, so a child process gets four), for both
+    shard maps, against the single-device jnp field."""
+    import os
+    import subprocess
+    import sys
+
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.pathsep.join(
+                   [src, os.environ.get("PYTHONPATH", "")]))
+    r = subprocess.run([sys.executable, "-c", _SHARDED_PARITY], env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stderr[-4000:]
+    assert "sharded parity ok" in r.stdout
 
 
 def test_vm_step_matches_visitor_dp(paper_graph, paper_trie, paper_partition):

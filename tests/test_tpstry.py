@@ -91,3 +91,40 @@ def test_snapshot_change_detection(paper_workload):
     trie.set_frequencies({q1.qhash: 0.9, q2.qhash: 0.1})
     changed = trie.changed_since_snapshot()
     assert changed.any()
+
+
+_TRIE_DIGEST = """
+import hashlib
+from repro.core.rpq import parse_rpq
+from repro.core.tpstry import TPSTry
+
+w = [(parse_rpq("Entity.(Entity)*.Entity"), 0.4),
+     (parse_rpq("Agent.Activity.Entity.Entity.Activity.Agent"), 0.2),
+     (parse_rpq("(Entity)*.Activity.Entity"), 0.2),
+     (parse_rpq("Entity.Activity.(Agent)*"), 0.2)]
+t = TPSTry.from_workload(w).compile(["Entity", "Activity", "Agent"])
+h = hashlib.sha256()
+for part in t.topology_signature():
+    h.update(part if isinstance(part, bytes) else repr(part).encode())
+h.update(t.p.tobytes())
+print(h.hexdigest())
+"""
+
+
+def test_compiled_trie_independent_of_string_hashing():
+    """Node ids follow insertion order, so the compiled trie — and with it
+    the jitted field and its persistent-cache key — must not depend on the
+    per-process string-hash seed that orders a query's string set."""
+    import os
+    import subprocess
+    import sys
+
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    digests = set()
+    for seed in ("1", "2", "3"):
+        env = dict(os.environ, PYTHONHASHSEED=seed, PYTHONPATH=src)
+        r = subprocess.run([sys.executable, "-c", _TRIE_DIGEST], env=env,
+                           capture_output=True, text=True, timeout=120)
+        assert r.returncode == 0, r.stderr[-2000:]
+        digests.add(r.stdout.strip())
+    assert len(digests) == 1

@@ -30,16 +30,23 @@ work as whole-frontier array operations:
 
 Internal iterations are therefore "inexpensive" in the paper's sense (§5):
 per-candidate cost is a handful of O(family-degree) vector ops.
+
+Given a parent span, an iteration traces its two phases as children:
+``swap.prepare`` (candidate queue, whole-iteration precomputes, batched
+singleton rows) and ``swap.walk`` (the sequential offer/receive walk),
+which ends with the walk's counters of :class:`SwapStats` as attributes.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
+import time
+from dataclasses import dataclass, field as dfield
 from typing import List, Optional, Tuple
 
 import numpy as np
 
 from repro.core.visitor import ExtroversionResult
 from repro.graphs.graph import LabelledGraph
+from repro.obs.trace import NOOP_SPAN, Span
 from repro.utils import get_logger
 
 log = get_logger("core.swap")
@@ -64,6 +71,16 @@ class SwapStats:
     accepted_offers: int
     rejected_offers: int
     candidates: int
+    # the walk's work, by path (not compared: they count how the engine
+    # reached its decisions, which the seed engine does differently)
+    singles_visited: int = dfield(default=0, compare=False)
+    # singleton candidates whose batched row was re-derived because a
+    # vertex of their 1-hop neighbourhood had moved
+    stale_rows: int = dfield(default=0, compare=False)
+    family_walks: int = dfield(default=0, compare=False)
+    family_members: int = dfield(default=0, compare=False)
+    # perf_counter seconds spent in the family branch
+    family_s: float = dfield(default=0.0, compare=False)
 
 
 def _concat_csr_edges(
@@ -239,13 +256,15 @@ def swap_iteration(
     cfg: SwapConfig,
     rng: np.random.Generator,
     candidate_mask: Optional[np.ndarray] = None,
+    parent: Optional[Span] = None,
 ) -> Tuple[np.ndarray, SwapStats]:
     """One internal TAPER iteration of offer/receive vertex swapping.
 
     ``candidate_mask`` (optional ``(n,)`` bool) seeds the candidate queue
     from a vertex subset only — used by ``OnlineTaper`` to run
     mutation-local invocations over the dirty frontier; ``None`` keeps the
-    full paper §3.1 queue.
+    full paper §3.1 queue.  ``parent`` (optional span) receives the
+    ``swap.prepare`` and ``swap.walk`` child spans (module doc).
 
     Produces bit-identical partitions and stats to the seed implementation
     (``repro.core.swap_ref.swap_iteration_reference``), but amortises almost
@@ -266,6 +285,8 @@ def swap_iteration(
     * candidates with multi-member families take the frontier-batched
       ``_family_of`` / ``_family_gains`` path against live state.
     """
+    parent = NOOP_SPAN if parent is None else parent
+    prepare = parent.child("swap.prepare")
     part = part.astype(np.int32).copy()
     n = g.n
     sizes = np.bincount(part, minlength=k).astype(np.int64)
@@ -283,6 +304,7 @@ def swap_iteration(
     moved = np.zeros(n, dtype=bool)
     stats = SwapStats(0, 0, 0, int(candidates.size))
     if candidates.size == 0:
+        prepare.end()
         return part, stats
 
     # ---- whole-iteration precomputes --------------------------------------
@@ -339,12 +361,18 @@ def swap_iteration(
     dirty = bytearray(n)  # vertices whose part/moved changed since the batch
     sizes_l = sizes.tolist()
     min_gain = cfg.min_gain
+    prepare.end()
 
+    walk = parent.child("swap.walk")
+    perf_counter = time.perf_counter
+    singles = stale = walks = members = 0
+    family_s = 0.0
     for ci, v in enumerate(cand_list):
         if moved[v]:
             continue
         home = int(part[v])
         if single_list[ci]:
+            singles += 1
             fresh = not dirty[v]
             if fresh:
                 for j in range(rp[v], rp[v + 1]):
@@ -357,6 +385,7 @@ def swap_iteration(
                 prefs = prefs_rows[row]
                 order = order_rows[row]
             else:
+                stale += 1
                 # 1-hop state changed: re-derive from live part[] (same
                 # arithmetic as the batch).  Preference rows built from
                 # ext_to are static — only the two-phase lazy prefs depend
@@ -398,6 +427,8 @@ def swap_iteration(
             continue
 
         # ---- multi-member family: frontier-batched path on live state ----
+        t_family = perf_counter()
+        walks += 1
         if dense:
             prefs_a = field.ext_to[v].copy()
         else:
@@ -406,6 +437,7 @@ def swap_iteration(
         order_a = np.argsort(-prefs_a)
         fam = _family_of(g, v, part, moved, rel_mass_out, rev, cfg)
         fs = int(fam.size)
+        members += fs
         gains_a = None  # computed on the first destination passing balance
         for dest in order_a:
             dest = int(dest)
@@ -427,4 +459,14 @@ def swap_iteration(
                 stats.accepted_offers += 1
                 break
             stats.rejected_offers += 1
+        family_s += perf_counter() - t_family
+    # free the walk's Python lists inside its span (millions of objects at
+    # scale), so that the two phases cover the whole iteration
+    del gains_rows, prefs_rows, order_rows, rp, dl, cand_list, row_list, \
+        single_list
+    stats.singles_visited, stats.stale_rows = singles, stale
+    stats.family_walks, stats.family_members = walks, members
+    stats.family_s = family_s
+    walk.end(singles_visited=singles, stale_rows=stale, family_walks=walks,
+             family_members=members, family_s=family_s)
     return part, stats

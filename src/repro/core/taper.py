@@ -346,6 +346,7 @@ class Taper:
                 backend=cfg.field_backend,
                 shard_map_source=cfg.shard_map_source,
                 halo_exchange=cfg.halo_exchange,
+                parent=sp,
             )
             hs = self._pre.get("_halo_stats")
             if hs:
@@ -353,13 +354,6 @@ class Taper:
                        halo_ratio=hs.get("halo_ratio", 0.0),
                        depth_steps=hs.get("depth_steps", 0),
                        n_shards=hs.get("n_shards", 0))
-                if self.tracer is not None and self.trace_ctx is not None:
-                    # per-depth accounting: one instant marker per DP depth
-                    # step, each carrying the bytes its halo exchange moved
-                    for d in range(int(hs.get("depth_steps", 0))):
-                        self.tracer.event(
-                            "field.depth", self.trace_ctx, depth=d + 1,
-                            halo_bytes=hs.get("halo_bytes_per_depth", 0))
         self._field_memo = (memo_key, fld)
         return fld
 
@@ -445,7 +439,7 @@ class Taper:
             with self._span("invocation.swap", iteration=it + 1) as swap_sp:
                 new_part, stats = swap_iteration(
                     self.g, part, fld, self.k, cfg.swap_config(), self._rng,
-                    candidate_mask=cand_mask,
+                    candidate_mask=cand_mask, parent=swap_sp,
                 )
                 swap_sp.set(moves=stats.moves)
             if stats.moves == 0:

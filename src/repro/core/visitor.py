@@ -74,6 +74,7 @@ import numpy as np
 
 from repro.core.tpstry import TrieArrays
 from repro.graphs.graph import LabelledGraph
+from repro.obs.trace import NOOP_SPAN, Span
 from repro.utils import get_logger
 
 log = get_logger("core.visitor")
@@ -146,6 +147,11 @@ def _build_field_fn(topology: Tuple, trie: TrieArrays, k: int, depth_cap: int,
     Topology (parent/label/leaf structure) is baked in as Python-level loop
     structure; probabilities arrive as runtime arrays.
 
+    Each phase of the program runs under a ``jax.named_scope``, so the
+    device trace's ops carry it in their ``op_name``: ``field.priors``
+    (per-edge inputs and the depth-1 priors), ``field.depth<d>`` (one DP
+    depth step) and ``field.aggregates`` (Pr, extroversion, ``ext_to``).
+
     Two implementations (numerically identical; tested against each other):
 
     * naive  — one gather + segment_sum pass over the edge list per trie
@@ -171,47 +177,51 @@ def _build_field_fn(topology: Tuple, trie: TrieArrays, k: int, depth_cap: int,
         if 1 <= depth[i] < max_depth and not is_leaf[i]
     ]
 
-    def _priors(vlabels, lab_vcount, p, n):
-        return _prior_columns(depth, labels_n, N, vlabels, lab_vcount, p, n)
+    def _inputs_and_priors(src, dst, vlabels, cnt, lab_vcount, part, p, n):
+        with jax.named_scope("field.priors"):
+            inv_cnt = 1.0 / jnp.maximum(cnt.astype(jnp.float32), 1.0)  # (n, L)
+            local = (part[src] == part[dst]).astype(jnp.float32)       # (m,)
+            dst_lab = vlabels[dst]                                     # (m,)
+            alpha = _prior_columns(depth, labels_n, N, vlabels, lab_vcount,
+                                   p, n)
+        return inv_cnt, local, dst_lab, alpha
 
-    def _aggregates(alpha, mass, src, dst, part, local, n, m):
-        return _field_aggregates(counted_nodes, k, dense_ext_to,
-                                 alpha, mass, src, dst, part, local, n)
+    def _aggregates(alpha, mass, src, dst, part, local, n):
+        with jax.named_scope("field.aggregates"):
+            return _field_aggregates(counted_nodes, k, dense_ext_to,
+                                     alpha, mass, src, dst, part, local, n)
 
     @partial(jax.jit, static_argnames=("n", "m"))
     def field_fn_naive(
         src, dst, vlabels, cnt, lab_vcount, part, p, cond_p, *, n: int, m: int
     ):
-        inv_cnt = 1.0 / jnp.maximum(cnt.astype(jnp.float32), 1.0)  # (n, L)
-        local = (part[src] == part[dst]).astype(jnp.float32)       # (m,)
-        dst_lab = vlabels[dst]                                     # (m,)
-        alpha = _priors(vlabels, lab_vcount, p, n)
+        inv_cnt, local, dst_lab, alpha = _inputs_and_priors(
+            src, dst, vlabels, cnt, lab_vcount, part, p, n)
 
         # --- DP steps + edge masses, one pass per depth>=2 node ---
         mass = jnp.zeros((m,), dtype=jnp.float32)
         for c in step_nodes:
             par, lc = int(parent[c]), int(labels_n[c])
-            contrib = (
-                alpha[src, par]
-                * cond_p[c]
-                * inv_cnt[src, lc]
-                * (dst_lab == lc).astype(jnp.float32)
-            )
-            mass = mass + contrib
-            # only local (intra-partition) extensions continue the path
-            alpha = alpha.at[:, c].add(
-                jax.ops.segment_sum(contrib * local, dst, num_segments=n)
-            )
-        return _aggregates(alpha, mass, src, dst, part, local, n, m)
+            with jax.named_scope(f"field.depth{int(depth[c])}"):
+                contrib = (
+                    alpha[src, par]
+                    * cond_p[c]
+                    * inv_cnt[src, lc]
+                    * (dst_lab == lc).astype(jnp.float32)
+                )
+                mass = mass + contrib
+                # only local (intra-partition) extensions continue the path
+                alpha = alpha.at[:, c].add(
+                    jax.ops.segment_sum(contrib * local, dst, num_segments=n)
+                )
+        return _aggregates(alpha, mass, src, dst, part, local, n)
 
     @partial(jax.jit, static_argnames=("n", "m"))
     def field_fn_fused(
         src, dst, vlabels, cnt, lab_vcount, part, p, cond_p, *, n: int, m: int
     ):
-        inv_cnt = 1.0 / jnp.maximum(cnt.astype(jnp.float32), 1.0)  # (n, L)
-        local = (part[src] == part[dst]).astype(jnp.float32)       # (m,)
-        dst_lab = vlabels[dst]                                     # (m,)
-        alpha = _priors(vlabels, lab_vcount, p, n)
+        inv_cnt, local, dst_lab, alpha = _inputs_and_priors(
+            src, dst, vlabels, cnt, lab_vcount, part, p, n)
 
         mass = jnp.zeros((m,), dtype=jnp.float32)
         for d in range(2, max_depth + 1):
@@ -220,20 +230,21 @@ def _build_field_fn(topology: Tuple, trie: TrieArrays, k: int, depth_cap: int,
                 continue
             pars = np.asarray([parent[c] for c in nodes_d])
             labs = np.asarray([labels_n[c] for c in nodes_d])
-            # one batched gather of the needed parent columns: (m, n_d)
-            # (column-slice first so the row gather moves n_d floats/edge,
-            # not the full trie row)
-            a_par = alpha[:, pars][src]
-            coef = cond_p[jnp.asarray(np.asarray(nodes_d))][None, :]
-            lab_mask = (dst_lab[:, None] == jnp.asarray(labs)[None, :])
-            ic = inv_cnt[:, labs][src]
-            contrib = a_par * coef * ic * lab_mask.astype(jnp.float32)
-            mass = mass + contrib.sum(axis=1)
-            # single segment_sum for the whole depth: (n, n_d)
-            upd = jax.ops.segment_sum(contrib * local[:, None], dst,
-                                      num_segments=n)
-            alpha = alpha.at[:, jnp.asarray(np.asarray(nodes_d))].add(upd)
-        return _aggregates(alpha, mass, src, dst, part, local, n, m)
+            with jax.named_scope(f"field.depth{d}"):
+                # one batched gather of the needed parent columns: (m, n_d)
+                # (column-slice first so the row gather moves n_d
+                # floats/edge, not the full trie row)
+                a_par = alpha[:, pars][src]
+                coef = cond_p[jnp.asarray(np.asarray(nodes_d))][None, :]
+                lab_mask = (dst_lab[:, None] == jnp.asarray(labs)[None, :])
+                ic = inv_cnt[:, labs][src]
+                contrib = a_par * coef * ic * lab_mask.astype(jnp.float32)
+                mass = mass + contrib.sum(axis=1)
+                # single segment_sum for the whole depth: (n, n_d)
+                upd = jax.ops.segment_sum(contrib * local[:, None], dst,
+                                          num_segments=n)
+                alpha = alpha.at[:, jnp.asarray(np.asarray(nodes_d))].add(upd)
+        return _aggregates(alpha, mass, src, dst, part, local, n)
 
     return field_fn_fused if fused else field_fn_naive
 
@@ -667,8 +678,7 @@ def _pallas_sharded_field(
         "n_frontier": sp.n_frontier,
         "hot_rows": sp.hot_pad,
         "sliced_rows": sp.hot_pad + int(sp.round_cap[1:].sum()),
-        # DP depth steps the kernel ran (each one is a halo exchange) —
-        # the invocation trace emits one field.depth event per step
+        # DP depth steps the kernel ran (each one is a halo exchange)
         "depth_steps": max(int(max_depth) - 1, 0),
     }
 
@@ -692,6 +702,7 @@ def extroversion_field(
     backend: str = "jnp",
     shard_map_source: str = "stripe",
     halo_exchange: str = "sliced",
+    parent: Optional[Span] = None,
 ) -> ExtroversionResult:
     """Compute the extroversion field of ``part`` under the workload trie.
 
@@ -719,6 +730,10 @@ def extroversion_field(
     moves per-shard-pair slices (``"sliced"``: a psum'd hot union plus
     ``S - 1`` ring ``ppermute`` rounds, padded per round) or the psum'd
     union frontier (``"psum"``).
+
+    ``parent`` (optional span) receives a ``field.fetch`` child around the
+    device-to-host copy of the outputs, with their total ``bytes``; the
+    device finishes before it opens, so it times the copy alone.
     """
     depth_cap = depth_cap or trie.max_depth
     pre = _precomputed if _precomputed is not None else {}
@@ -760,20 +775,27 @@ def extroversion_field(
         )
     else:
         raise ValueError(f"unknown field backend {backend!r}")
+    parent = NOOP_SPAN if parent is None else parent
+    if parent is not NOOP_SPAN:
+        # np.asarray waits anyway; waiting first keeps the device's tail
+        # out of the fetch span
+        jax.block_until_ready(out)
+    with parent.child("field.fetch") as fetch:
+        host = [np.asarray(a) for a in out]
+        fetch.set(bytes=sum(a.nbytes for a in host))
     if dense_ext_to:
-        alpha, pr, mass, extro_mass, extroversion, ext_to = out
-        ext_to = np.asarray(ext_to)
+        alpha, pr, mass, extro_mass, extroversion, ext_to = host
     else:
-        alpha, pr, mass, extro_mass, extroversion = out
+        alpha, pr, mass, extro_mass, extroversion = host
         ext_to = None
     return ExtroversionResult(
-        alpha=np.asarray(alpha),
-        pr=np.asarray(pr),
-        edge_mass=np.asarray(mass),
-        extro_mass=np.asarray(extro_mass),
-        extroversion=np.asarray(extroversion),
+        alpha=alpha,
+        pr=pr,
+        edge_mass=mass,
+        extro_mass=extro_mass,
+        extroversion=extroversion,
         ext_to=ext_to,
-        total_extroversion=float(np.asarray(extro_mass).sum()),
+        total_extroversion=float(extro_mass.sum()),
     )
 
 

@@ -2,15 +2,15 @@
 
 A **trace** is the causal story of one unit of work — a served request
 (queue admission → micro-batch drain → enum sweeps → reply), a TAPER
-invocation (input snapshot → field depth steps → swap iterations → commit
-→ shard re-deal), an ingest group (journal append → apply → ship →
-follower apply), or a failover (crash → fence → promotion → first
-answer).  A trace is identified by a ``trace_id`` string; its **spans**
-are named intervals on the monotonic clock, each carrying a
-``span_id``/``parent_id`` pair and free-form key/value attributes.  Trace
-ids travel across nodes on ``ServeTicket``s and piggybacked inside
-replication-frame payloads, so a follower's apply or a router's
-first-answer-after-failover *joins* the originating trace
+invocation (input snapshot → field evaluation and its fetch → swap
+iterations and their phases → commit → shard re-deal), an ingest group
+(journal append → apply → ship → follower apply), or a failover (crash →
+fence → promotion → first answer).  A trace is identified by a
+``trace_id`` string; its **spans** are named intervals on the monotonic
+clock, each carrying a ``span_id``/``parent_id`` pair and free-form
+key/value attributes.  Trace ids travel across nodes on ``ServeTicket``s
+and piggybacked inside replication-frame payloads, so a follower's apply
+or a router's first-answer-after-failover *joins* the originating trace
 (:meth:`Tracer.join`) instead of starting a disconnected one.
 
 The hot-path contract is *pay nothing when off*:
@@ -26,6 +26,13 @@ The hot-path contract is *pay nothing when off*:
 
 Finished spans land in a bounded ring (oldest evicted) and export as
 dicts (:meth:`Tracer.spans`) or JSONL (:meth:`Tracer.export_jsonl`).
+
+A span opened by :meth:`Tracer.start` also opens a
+``jax.profiler.TraceAnnotation`` of its name and closes it in
+:meth:`Span.end`, on whichever thread ends it.  While a profiler session
+runs, every sampled span so sits on the profiler's ``/host:CPU`` plane,
+on the same clock as the device's operations; with no session the
+annotation records nothing, and no-op spans open none.
 """
 from __future__ import annotations
 
@@ -35,6 +42,8 @@ import threading
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["NOOP_SPAN", "NOOP_TRACE", "Span", "TraceContext", "Tracer"]
 
@@ -66,7 +75,7 @@ class Span:
     (``with tracer.start(...) as sp:``) or via explicit :meth:`end`."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "t0", "t1",
-                 "attrs", "_tracer")
+                 "attrs", "_tracer", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, trace_id: str,
                  span_id: int, parent_id: int, attrs: Dict[str, Any]):
@@ -78,6 +87,7 @@ class Span:
         self.t0 = time.monotonic()
         self.t1: Optional[float] = None
         self.attrs = attrs
+        self._annotation: Optional[TraceAnnotation] = None
 
     @property
     def t_wall(self) -> float:
@@ -94,12 +104,19 @@ class Span:
         """A child context: same trace, this span as the parent."""
         return TraceContext(self.trace_id, self.span_id, True)
 
+    def child(self, name: str, **attrs) -> "Span":
+        """Open a span under this one, on the same tracer."""
+        return self._tracer.start(name, self.context(), **attrs)
+
     def end(self, **attrs) -> None:
         """Close the span (idempotent) and hand it to the tracer's ring."""
         if self.t1 is not None:
             return
         if attrs:
             self.attrs.update(attrs)
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         self.t1 = time.monotonic()
         self._tracer._record(self)
 
@@ -134,6 +151,9 @@ class _NoopSpan:
 
     def context(self) -> TraceContext:
         return NOOP_TRACE
+
+    def child(self, name: str, **attrs) -> "_NoopSpan":
+        return self
 
     def end(self, **attrs) -> None:
         pass
@@ -193,15 +213,19 @@ class Tracer:
 
     # -- spans ----------------------------------------------------------------
     def start(self, name: str, ctx: TraceContext, **attrs):
-        """Open a span under ``ctx`` (its ``span_id`` is the parent)."""
+        """Open a span under ``ctx`` (its ``span_id`` is the parent), and
+        its profiler annotation (module doc)."""
         if not self.enabled or not ctx.sampled:
             return NOOP_SPAN
-        return Span(self, name, ctx.trace_id, next(self._span_seq),
-                    ctx.span_id, attrs)
+        sp = Span(self, name, ctx.trace_id, next(self._span_seq),
+                  ctx.span_id, attrs)
+        sp._annotation = TraceAnnotation(name)
+        sp._annotation.__enter__()
+        return sp
 
     def event(self, name: str, ctx: TraceContext, **attrs) -> None:
         """Record an instant (zero-duration) span — a point-in-time marker
-        such as a per-depth halo accounting step or a fence advancing."""
+        such as a shard re-deal or a fence advancing."""
         if not self.enabled or not ctx.sampled:
             return
         sp = Span(self, name, ctx.trace_id, next(self._span_seq),
@@ -224,12 +248,6 @@ class Tracer:
                and (name is None or s.name == name)]
         out.sort(key=lambda s: (s.t0, s.span_id))
         return [s.to_dict() for s in out]
-
-    def trace_ids(self) -> List[str]:
-        seen: Dict[str, None] = {}
-        for s in list(self._spans):
-            seen.setdefault(s.trace_id)
-        return list(seen)
 
     def export_jsonl(self, path) -> int:
         """Write every retained span as one JSON object per line; returns

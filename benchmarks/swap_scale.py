@@ -1,6 +1,6 @@
 """Swap-engine scaling: vectorised vs seed swap, per-phase invocation split.
 
-Acceptance benchmark for the frontier-batched swap engine
+Acceptance benchmark for the batched swap engine
 (repro.core.swap): on a 50k-vertex, k=8 synthetic graph one internal
 iteration's swap phase must be >= 5x faster than the seed per-vertex
 implementation (repro.core.swap_ref), with bit-identical partitions.

@@ -1,4 +1,4 @@
-"""Vertex swapping (paper §3.1, §5.5) — frontier-batched numpy engine.
+"""Vertex swapping (paper §3.1, §5.5) — batched numpy prepare, scalar walk.
 
 Per TAPER internal iteration:
 
@@ -18,18 +18,25 @@ All probability masses come precomputed from the extroversion field (the jit
 DP).  The seed implementation walked each family one neighbour at a time with
 an ``np.searchsorted`` reverse-edge lookup per neighbour pair; this version
 keeps the offer/receive semantics and balance constraint bit-identical (see
-``repro.core.swap_ref`` + tests/test_swap_parity.py) but does all per-family
-work as whole-frontier array operations:
+``repro.core.swap_ref`` + tests/test_swap_parity.py) but moves the lookups
+into whole-iteration precomputes:
 
-* family expansion expands an entire BFS frontier per step — one
-  concatenated CSR slice, one gather of the cached
-  ``LabelledGraph.reverse_edge_index``, one first-occurrence dedup;
-* family gain/loss is a single masked segment-sum (``np.bincount``) over the
-  family's incident edge set, yielding the gains toward *all* ``k``
-  destinations at once (the seed recomputed a Python loop per destination).
+* the *joinable* edge relation ``join_e`` (``dst`` may join ``src``'s
+  family) is one vectorised test over all edges at iteration start.  For an
+  unmoved candidate every family member sits in its iteration-start
+  partition, so the live membership test reduces to ``join_e[e]`` and "the
+  neighbour has not moved";
+* singleton candidates (most of them) get their k-destination gain and
+  preference rows from batched ``bincount``/``argsort`` passes;
+* multi-member families (2–3 members on average) are flood-filled and
+  their gains toward all ``k`` partitions summed in scalar Python over the
+  walk's host lists, in CSR edge order — the float64 additions of
+  ``np.bincount``, in the same order, so the same bits.  A family whose
+  flood fill reaches a hub (degree above ``max_scan_neighbors``, whose edges
+  the seed's guard scans in ``argsort`` order) takes the whole-frontier
+  numpy path (``_family_of`` / ``_family_gains``) instead.
 
-Internal iterations are therefore "inexpensive" in the paper's sense (§5):
-per-candidate cost is a handful of O(family-degree) vector ops.
+Internal iterations are therefore "inexpensive" in the paper's sense (§5).
 
 Given a parent span, an iteration traces its two phases as children:
 ``swap.prepare`` (candidate queue, whole-iteration precomputes, batched
@@ -79,6 +86,8 @@ class SwapStats:
     stale_rows: int = dfield(default=0, compare=False)
     family_walks: int = dfield(default=0, compare=False)
     family_members: int = dfield(default=0, compare=False)
+    # family walks that took the scalar path (the rest reached a hub)
+    family_scalar_walks: int = dfield(default=0, compare=False)
     # perf_counter seconds spent in the family branch
     family_s: float = dfield(default=0.0, compare=False)
 
@@ -115,22 +124,20 @@ def _frontier_edge_indices(
 def _family_of(
     g: LabelledGraph,
     v: int,
-    part: np.ndarray,
     moved: np.ndarray,
+    join_e: np.ndarray,
     rel_mass_out: np.ndarray,
-    rev: np.ndarray,
     cfg: SwapConfig,
 ) -> np.ndarray:
     """Flood-fill family: local vertices likely (> threshold) to traverse
-    *to* a current member (paper §5.5).  rel_mass_out[e] = edge_mass[e] /
-    Pr(src[e]) — the probability that a traversal out of src follows e.
+    *to* a current member (paper §5.5), for families that reach a hub.
+    rel_mass_out[e] = edge_mass[e] / Pr(src[e]) — the probability that a
+    traversal out of src follows e — orders a hub's edges.
 
-    Whole frontiers expand at once: for frontier edges ``e = (w, u)`` the
-    membership test reads the reverse edge ``(u, w)`` through the cached
-    ``reverse_edge_index`` gather, and candidates join in first-occurrence
-    order (identical to the seed's sequential scan) up to
+    Whole frontiers expand at once: frontier edge ``e = (w, u)`` recruits
+    ``u`` when ``join_e[e]`` and ``u`` has not moved, and candidates join in
+    first-occurrence order (identical to the seed's sequential scan) up to
     ``family_max_size``."""
-    home = part[v]
     fam = np.array([v], dtype=np.int64)
     frontier = fam
     while frontier.size and fam.size < cfg.family_max_size:
@@ -139,17 +146,7 @@ def _family_of(
         if eidx.size == 0:
             break
         nbrs = g.dst[eidx].astype(np.int64)
-        # traversal from u to w is the reverse edge (u, w) of e = (w, u)
-        r = rev[eidx]
-        ok = (
-            (part[nbrs] == home)
-            & ~moved[nbrs]
-            & (r >= 0)
-            & ~np.isin(nbrs, fam)
-            # r == -1 rows are already masked; the clamped gather is harmless
-            & (rel_mass_out[np.maximum(r, 0)] > cfg.family_threshold)
-        )
-        cand = nbrs[ok]
+        cand = nbrs[join_e[eidx] & ~moved[nbrs] & ~np.isin(nbrs, fam)]
         if cand.size == 0:
             break
         # first-occurrence dedup preserves the seed's sequential join order
@@ -166,8 +163,7 @@ def _family_gains(
     g: LabelledGraph,
     fam: np.ndarray,
     part: np.ndarray,
-    edge_mass: np.ndarray,
-    rev: np.ndarray,
+    sym_mass: np.ndarray,
     k: int,
 ) -> np.ndarray:
     """``(k,)`` float64 — traversal mass between the family and *each*
@@ -184,11 +180,49 @@ def _family_gains(
         return np.zeros(k, dtype=np.float64)
     nbrs = g.dst[eidx].astype(np.int64)
     ext = ~np.isin(nbrs, fam)
-    eidx, nbrs = eidx[ext], nbrs[ext]
-    r = rev[eidx]
-    m = edge_mass[eidx].astype(np.float64) + np.where(
-        r >= 0, edge_mass[np.maximum(r, 0)].astype(np.float64), 0.0)
-    return np.bincount(part[nbrs], weights=m, minlength=k)
+    return np.bincount(part[nbrs[ext]], weights=sym_mass[eidx[ext]],
+                       minlength=k)
+
+
+def _family_scalar(
+    v: int, rp: list, dl: list, join: bytes, moved: bytearray,
+    max_size: int, cap: int,
+) -> Optional[List[int]]:
+    """``_family_of`` in scalar Python over the walk's host lists: members
+    in the same first-occurrence order, truncated the same way.  ``None``
+    when a member to expand has more than ``cap`` edges (a hub, whose edges
+    the guard orders by ``argsort``): the caller then takes the numpy
+    path."""
+    fam = [v]
+    for w in fam:  # breadth-first: the loop also visits appended members
+        if len(fam) >= max_size:
+            break
+        lo, hi = rp[w], rp[w + 1]
+        if hi - lo > cap:
+            return None
+        for j in range(lo, hi):
+            if join[j]:
+                u = dl[j]
+                if not moved[u] and u not in fam:
+                    fam.append(u)
+                    if len(fam) >= max_size:
+                        return fam
+    return fam
+
+
+def _family_gains_scalar(
+    fam: List[int], rp: list, dl: list, part: np.ndarray,
+    sym_mass: np.ndarray, k: int,
+) -> List[float]:
+    """``_family_gains`` in scalar Python: the same float64 additions, in
+    the same (CSR edge) order, as its ``np.bincount``."""
+    gains = [0.0] * k
+    for w in fam:
+        for j in range(rp[w], rp[w + 1]):
+            u = dl[j]
+            if u not in fam:
+                gains[part[u]] += sym_mass[j]
+    return gains
 
 
 def _candidate_queue(
@@ -282,8 +316,15 @@ def swap_iteration(
       precomputed rows; a batched row is re-derived per candidate only when
       a vertex in its 1-hop neighbourhood has moved since the batch (the
       gains/prefs of v depend only on ``part``/``moved`` over N(v) ∪ {v});
-    * candidates with multi-member families take the frontier-batched
-      ``_family_of`` / ``_family_gains`` path against live state.
+    * candidates with multi-member families are flood-filled on live state
+      by ``_family_scalar``: a member ``u`` of an unmoved candidate's family
+      is itself unmoved, so ``part[u]`` is its iteration-start partition and
+      the seed's test (same partition, unmoved, reverse-edge traversal
+      probability above the threshold) is ``join_e[e]`` and not ``moved[u]``.
+      Their gains come from ``_family_gains_scalar``.  Families whose fill
+      reaches a hub (degree above ``cfg.max_scan_neighbors``) take the
+      frontier-batched ``_family_of`` / ``_family_gains`` path instead;
+      ``SwapStats.family_scalar_walks`` counts the others.
     """
     parent = NOOP_SPAN if parent is None else parent
     prepare = parent.child("swap.prepare")
@@ -301,7 +342,6 @@ def swap_iteration(
     rev_c = np.maximum(rev, 0)
 
     candidates = _candidate_queue(part, field, k, cfg, candidate_mask)
-    moved = np.zeros(n, dtype=bool)
     stats = SwapStats(0, 0, 0, int(candidates.size))
     if candidates.size == 0:
         prepare.end()
@@ -355,20 +395,25 @@ def swap_iteration(
     # ---- sequential offer/receive walk (pure Python on cached rows) -------
     rp = g.row_ptr.tolist()
     dl = g.dst.tolist()
+    join_l = join_e.tobytes()
     cand_list = candidates.tolist()
     row_list = row_of.tolist()
     single_list = is_single.tolist()
-    dirty = bytearray(n)  # vertices whose part/moved changed since the batch
+    # the moved vertices: those whose part changed since the batch; the
+    # bool view serves the numpy family path
+    dirty = bytearray(n)
+    moved = np.frombuffer(dirty, dtype=bool)
     sizes_l = sizes.tolist()
     min_gain = cfg.min_gain
     prepare.end()
 
     walk = parent.child("swap.walk")
     perf_counter = time.perf_counter
-    singles = stale = walks = members = 0
+    fam_max, hub_deg = cfg.family_max_size, cfg.max_scan_neighbors
+    singles = stale = walks = members = scalar_walks = 0
     family_s = 0.0
     for ci, v in enumerate(cand_list):
-        if moved[v]:
+        if dirty[v]:
             continue
         home = int(part[v])
         if single_list[ci]:
@@ -416,7 +461,6 @@ def swap_iteration(
                                         minlength=k)
                 if gains[dest] > gains[home] + min_gain:
                     part[v] = dest
-                    moved[v] = True
                     dirty[v] = 1
                     sizes_l[home] -= 1
                     sizes_l[dest] += 1
@@ -426,7 +470,7 @@ def swap_iteration(
                 stats.rejected_offers += 1
             continue
 
-        # ---- multi-member family: frontier-batched path on live state ----
+        # ---- multi-member family: scalar path on live state ---------------
         t_family = perf_counter()
         walks += 1
         if dense:
@@ -435,8 +479,14 @@ def swap_iteration(
             prefs_a = _lazy_prefs(g, v, home, part, field, k)
         prefs_a[home] = -np.inf
         order_a = np.argsort(-prefs_a)
-        fam = _family_of(g, v, part, moved, rel_mass_out, rev, cfg)
-        fs = int(fam.size)
+        fam = _family_scalar(v, rp, dl, join_l, dirty, fam_max, hub_deg)
+        hub = fam is None
+        if hub:
+            fam_a = _family_of(g, v, moved, join_e, rel_mass_out, cfg)
+            fam = fam_a.tolist()
+        else:
+            scalar_walks += 1
+        fs = len(fam)
         members += fs
         gains_a = None  # computed on the first destination passing balance
         for dest in order_a:
@@ -447,11 +497,12 @@ def swap_iteration(
                 stats.rejected_offers += 1
                 continue
             if gains_a is None:
-                gains_a = _family_gains(g, fam, part, field.edge_mass, rev, k)
-            if float(gains_a[dest]) > float(gains_a[home]) + min_gain:
+                gains_a = (_family_gains(g, fam_a, part, sym_mass, k) if hub
+                           else _family_gains_scalar(fam, rp, dl, part,
+                                                     sym_mass, k))
+            if gains_a[dest] > gains_a[home] + min_gain:
                 part[fam] = dest
-                moved[fam] = True
-                for u in fam.tolist():
+                for u in fam:
                     dirty[u] = 1
                 sizes_l[home] -= fs
                 sizes_l[dest] += fs
@@ -466,7 +517,8 @@ def swap_iteration(
         single_list
     stats.singles_visited, stats.stale_rows = singles, stale
     stats.family_walks, stats.family_members = walks, members
-    stats.family_s = family_s
+    stats.family_scalar_walks, stats.family_s = scalar_walks, family_s
     walk.end(singles_visited=singles, stale_rows=stale, family_walks=walks,
-             family_members=members, family_s=family_s)
+             family_members=members, family_scalar_walks=scalar_walks,
+             family_s=family_s)
     return part, stats

@@ -3,9 +3,9 @@
 This is the original per-vertex Python implementation of ``swap_iteration``:
 flood-fill families via per-neighbour ``np.searchsorted`` reverse-edge
 lookups and per-destination gain loops.  ``repro.core.swap`` re-implements
-the same semantics with frontier-batched numpy; the parity suite
-(tests/test_swap_parity.py) and ``benchmarks/swap_scale.py`` hold the two
-bit-identical on random labelled graphs.
+the same semantics with batched numpy precomputes and a scalar walk; the
+parity suite (tests/test_swap_parity.py) and ``benchmarks/swap_scale.py``
+hold the two bit-identical on random labelled graphs.
 
 Do not optimise this module — its value is being the unchanged oracle.
 """

@@ -31,10 +31,9 @@ into whole-iteration precomputes:
 * multi-member families (2–3 members on average) are flood-filled and
   their gains toward all ``k`` partitions summed in scalar Python over the
   walk's host lists, in CSR edge order — the float64 additions of
-  ``np.bincount``, in the same order, so the same bits.  A family whose
-  flood fill reaches a hub (degree above ``max_scan_neighbors``, whose edges
-  the seed's guard scans in ``argsort`` order) takes the whole-frontier
-  numpy path (``_family_of`` / ``_family_gains``) instead.
+  ``np.bincount``, in the same order, so the same bits.  The fill expands
+  a hub over only its ``max_scan_neighbors`` highest-``rel_mass_out``
+  edges, in the seed guard's ``argsort`` order.
 
 Internal iterations are therefore "inexpensive" in the paper's sense (§5).
 
@@ -86,8 +85,6 @@ class SwapStats:
     stale_rows: int = dfield(default=0, compare=False)
     family_walks: int = dfield(default=0, compare=False)
     family_members: int = dfield(default=0, compare=False)
-    # family walks that took the scalar path (the rest reached a hub)
-    family_scalar_walks: int = dfield(default=0, compare=False)
     # perf_counter seconds spent in the family branch
     family_s: float = dfield(default=0.0, compare=False)
 
@@ -100,107 +97,30 @@ def _concat_csr_edges(
     return g.edge_indices_of(vs), cnts
 
 
-def _frontier_edge_indices(
-    g: LabelledGraph, frontier: np.ndarray, rel_mass_out: np.ndarray, cap: int
-) -> np.ndarray:
-    """Concatenated CSR edge indices of every frontier vertex, in frontier
-    order (each vertex's edges in CSR order).  Vertices whose degree exceeds
-    ``cap`` keep only their ``cap`` highest-``rel_mass_out`` edges — the same
-    hub guard (and the same tie-breaking ``argsort`` call) as the seed."""
-    starts = g.row_ptr[frontier]
-    cnts = g.row_ptr[frontier + 1] - starts
-    if not (cnts > cap).any():
-        return _concat_csr_edges(g, frontier)[0]
-    chunks: List[np.ndarray] = []
-    for lo, c in zip(starts, cnts):
-        eidx = np.arange(lo, lo + c, dtype=np.int64)
-        if c > cap:
-            keep = np.argsort(-rel_mass_out[eidx])[:cap]
-            eidx = eidx[keep]
-        chunks.append(eidx)
-    return np.concatenate(chunks)
-
-
-def _family_of(
-    g: LabelledGraph,
-    v: int,
-    moved: np.ndarray,
-    join_e: np.ndarray,
-    rel_mass_out: np.ndarray,
-    cfg: SwapConfig,
-) -> np.ndarray:
-    """Flood-fill family: local vertices likely (> threshold) to traverse
-    *to* a current member (paper §5.5), for families that reach a hub.
-    rel_mass_out[e] = edge_mass[e] / Pr(src[e]) — the probability that a
-    traversal out of src follows e — orders a hub's edges.
-
-    Whole frontiers expand at once: frontier edge ``e = (w, u)`` recruits
-    ``u`` when ``join_e[e]`` and ``u`` has not moved, and candidates join in
-    first-occurrence order (identical to the seed's sequential scan) up to
-    ``family_max_size``."""
-    fam = np.array([v], dtype=np.int64)
-    frontier = fam
-    while frontier.size and fam.size < cfg.family_max_size:
-        eidx = _frontier_edge_indices(g, frontier, rel_mass_out,
-                                      cfg.max_scan_neighbors)
-        if eidx.size == 0:
-            break
-        nbrs = g.dst[eidx].astype(np.int64)
-        cand = nbrs[join_e[eidx] & ~moved[nbrs] & ~np.isin(nbrs, fam)]
-        if cand.size == 0:
-            break
-        # first-occurrence dedup preserves the seed's sequential join order
-        _, first = np.unique(cand, return_index=True)
-        cand = cand[np.sort(first)]
-        room = cfg.family_max_size - fam.size
-        cand = cand[:room]
-        fam = np.concatenate([fam, cand])
-        frontier = cand
-    return fam
-
-
-def _family_gains(
-    g: LabelledGraph,
-    fam: np.ndarray,
-    part: np.ndarray,
-    sym_mass: np.ndarray,
-    k: int,
-) -> np.ndarray:
-    """``(k,)`` float64 — traversal mass between the family and *each*
-    partition (both edge directions), as one masked segment-sum over the
-    family's incident edges.
-
-    ``gains[dest]`` is the receiver gain of moving the family to ``dest``;
-    ``gains[home]`` is the sender loss.  Family-internal edges move with the
-    family and edges to third partitions stay cut, so neither affects the
-    decision.  (The seed recomputed this with Python loops once per
-    destination attempt.)"""
-    eidx, _ = _concat_csr_edges(g, fam)
-    if eidx.size == 0:
-        return np.zeros(k, dtype=np.float64)
-    nbrs = g.dst[eidx].astype(np.int64)
-    ext = ~np.isin(nbrs, fam)
-    return np.bincount(part[nbrs[ext]], weights=sym_mass[eidx[ext]],
-                       minlength=k)
-
-
 def _family_scalar(
     v: int, rp: list, dl: list, join: bytes, moved: bytearray,
-    max_size: int, cap: int,
-) -> Optional[List[int]]:
-    """``_family_of`` in scalar Python over the walk's host lists: members
-    in the same first-occurrence order, truncated the same way.  ``None``
-    when a member to expand has more than ``cap`` edges (a hub, whose edges
-    the guard orders by ``argsort``): the caller then takes the numpy
-    path."""
+    rel_mass_out: np.ndarray, max_size: int, cap: int,
+) -> List[int]:
+    """Flood-fill family of an unmoved ``v``: local vertices likely
+    (> threshold) to traverse *to* a current member (paper §5.5), in scalar
+    Python over the walk's host lists.  Edge ``j = (w, u)`` of a member
+    ``w`` recruits ``u`` when ``join[j]`` and ``u`` has not moved; members
+    are scanned breadth-first, each one's edges in CSR order, so members
+    join in the seed's first-occurrence order, truncated at ``max_size``.
+    A member with more than ``cap`` edges (a hub) is scanned over only its
+    ``cap`` highest-``rel_mass_out`` edges, in the seed guard's ``argsort``
+    order (``rel_mass_out[e]`` = probability that a traversal out of
+    ``src[e]`` follows ``e``)."""
     fam = [v]
     for w in fam:  # breadth-first: the loop also visits appended members
         if len(fam) >= max_size:
             break
         lo, hi = rp[w], rp[w + 1]
         if hi - lo > cap:
-            return None
-        for j in range(lo, hi):
+            edges = (lo + np.argsort(-rel_mass_out[lo:hi])[:cap]).tolist()
+        else:
+            edges = range(lo, hi)
+        for j in edges:
             if join[j]:
                 u = dl[j]
                 if not moved[u] and u not in fam:
@@ -214,8 +134,16 @@ def _family_gains_scalar(
     fam: List[int], rp: list, dl: list, part: np.ndarray,
     sym_mass: np.ndarray, k: int,
 ) -> List[float]:
-    """``_family_gains`` in scalar Python: the same float64 additions, in
-    the same (CSR edge) order, as its ``np.bincount``."""
+    """``(k,)`` traversal mass between the family and *each* partition
+    (both edge directions, ``sym_mass``), summed over the members' edges in
+    CSR order: the float64 additions of a ``np.bincount`` over the same
+    edges, in the same order.
+
+    ``gains[dest]`` is the receiver gain of moving the family to ``dest``;
+    ``gains[home]`` is the sender loss.  Family-internal edges move with the
+    family and edges to third partitions stay cut, so neither affects the
+    decision.  (The seed recomputed this with Python loops once per
+    destination attempt.)"""
     gains = [0.0] * k
     for w in fam:
         for j in range(rp[w], rp[w + 1]):
@@ -321,10 +249,7 @@ def swap_iteration(
       is itself unmoved, so ``part[u]`` is its iteration-start partition and
       the seed's test (same partition, unmoved, reverse-edge traversal
       probability above the threshold) is ``join_e[e]`` and not ``moved[u]``.
-      Their gains come from ``_family_gains_scalar``.  Families whose fill
-      reaches a hub (degree above ``cfg.max_scan_neighbors``) take the
-      frontier-batched ``_family_of`` / ``_family_gains`` path instead;
-      ``SwapStats.family_scalar_walks`` counts the others.
+      Their gains come from ``_family_gains_scalar``.
     """
     parent = NOOP_SPAN if parent is None else parent
     prepare = parent.child("swap.prepare")
@@ -399,10 +324,7 @@ def swap_iteration(
     cand_list = candidates.tolist()
     row_list = row_of.tolist()
     single_list = is_single.tolist()
-    # the moved vertices: those whose part changed since the batch; the
-    # bool view serves the numpy family path
-    dirty = bytearray(n)
-    moved = np.frombuffer(dirty, dtype=bool)
+    dirty = bytearray(n)  # the moved vertices (part changed since the batch)
     sizes_l = sizes.tolist()
     min_gain = cfg.min_gain
     prepare.end()
@@ -410,7 +332,7 @@ def swap_iteration(
     walk = parent.child("swap.walk")
     perf_counter = time.perf_counter
     fam_max, hub_deg = cfg.family_max_size, cfg.max_scan_neighbors
-    singles = stale = walks = members = scalar_walks = 0
+    singles = stale = walks = members = 0
     family_s = 0.0
     for ci, v in enumerate(cand_list):
         if dirty[v]:
@@ -479,13 +401,8 @@ def swap_iteration(
             prefs_a = _lazy_prefs(g, v, home, part, field, k)
         prefs_a[home] = -np.inf
         order_a = np.argsort(-prefs_a)
-        fam = _family_scalar(v, rp, dl, join_l, dirty, fam_max, hub_deg)
-        hub = fam is None
-        if hub:
-            fam_a = _family_of(g, v, moved, join_e, rel_mass_out, cfg)
-            fam = fam_a.tolist()
-        else:
-            scalar_walks += 1
+        fam = _family_scalar(v, rp, dl, join_l, dirty, rel_mass_out,
+                             fam_max, hub_deg)
         fs = len(fam)
         members += fs
         gains_a = None  # computed on the first destination passing balance
@@ -497,9 +414,7 @@ def swap_iteration(
                 stats.rejected_offers += 1
                 continue
             if gains_a is None:
-                gains_a = (_family_gains(g, fam_a, part, sym_mass, k) if hub
-                           else _family_gains_scalar(fam, rp, dl, part,
-                                                     sym_mass, k))
+                gains_a = _family_gains_scalar(fam, rp, dl, part, sym_mass, k)
             if gains_a[dest] > gains_a[home] + min_gain:
                 part[fam] = dest
                 for u in fam:
@@ -517,8 +432,7 @@ def swap_iteration(
         single_list
     stats.singles_visited, stats.stale_rows = singles, stale
     stats.family_walks, stats.family_members = walks, members
-    stats.family_scalar_walks, stats.family_s = scalar_walks, family_s
+    stats.family_s = family_s
     walk.end(singles_visited=singles, stale_rows=stale, family_walks=walks,
-             family_members=members, family_scalar_walks=scalar_walks,
-             family_s=family_s)
+             family_members=members, family_s=family_s)
     return part, stats

@@ -1,6 +1,6 @@
 """Parity suite: the vectorised swap engine and Pallas field vs their seeds.
 
-The frontier-batched ``swap_iteration`` must produce *bit-identical*
+The vectorised ``swap_iteration`` must produce *bit-identical*
 partitions and stats to the seed per-vertex implementation
 (``repro.core.swap_ref``) — same candidate order, same families, same
 offer/receive decisions, same rejected-offer counts — across random labelled
@@ -9,12 +9,15 @@ graphs, both ``ext_to`` modes, and chained iterations.
 The Pallas-backed extroversion field is held to numerical (not bit) parity
 with the fused jnp oracle: same DP, different op order.
 """
+import dataclasses
+
 import numpy as np
 import pytest
 
 from repro.core.rpq import parse_rpq
-from repro.core.swap import (SwapConfig, _family_gains, _family_gains_scalar,
-                             _family_of, _family_scalar, swap_iteration)
+from repro.core import swap_ref
+from repro.core.swap import (SwapConfig, _family_gains_scalar, _family_scalar,
+                             swap_iteration)
 from repro.core.swap_ref import swap_iteration_reference
 from repro.core.taper import Taper, TaperConfig
 from repro.core.tpstry import TPSTry
@@ -72,30 +75,41 @@ def test_swap_iteration_bit_identical_nondefault_config():
     assert s_new == s_ref
 
 
+def _jittered(fld, seed):
+    """``fld`` with each edge's mass scaled by its own factor in [0.5, 1.5):
+    a vertex's edges then differ in ``rel_mass_out``, so the hub guard's
+    order is not the CSR order its ties would give."""
+    f = np.random.default_rng(seed).uniform(0.5, 1.5, fld.edge_mass.size)
+    return dataclasses.replace(
+        fld, edge_mass=(fld.edge_mass * f).astype(fld.edge_mass.dtype))
+
+
 FAMILY_CASES = {
     # a loose threshold makes many multi-member families; no vertex has more
-    # edges than the default hub guard, so every family takes the scalar path
-    "scalar": (7, 4, SwapConfig(family_threshold=0.3), "scalar"),
-    # families truncated at three members, on the scalar path
-    "scalar-capped": (11, 3, SwapConfig(family_threshold=0.3,
-                                        family_max_size=3), "scalar"),
-    # a hub guard of 8 edges sends the families whose fill reaches a vertex
-    # of higher degree down the numpy path, the others down the scalar one
+    # edges than the default hub guard
+    "loose": (7, 4, SwapConfig(family_threshold=0.3)),
+    # families truncated at three members
+    "loose-capped": (11, 3, SwapConfig(family_threshold=0.3,
+                                       family_max_size=3)),
+    # a hub guard of 8 edges: the fill scans a hub's 8 likeliest edges
+    # only, on jittered edge masses
     "hub-guard": (7, 4, SwapConfig(family_threshold=0.3,
-                                   max_scan_neighbors=8), "both"),
+                                   max_scan_neighbors=8)),
 }
 
 
 @pytest.mark.parametrize("case", list(FAMILY_CASES))
 @pytest.mark.parametrize("dense", [True, False], ids=["dense", "two-phase"])
 def test_family_paths_bit_identical(case, dense):
-    """Multi-member families on the scalar and on the numpy (hub) path give
+    """Multi-member families, with and without hubs under the guard, give
     the seed's partitions and stats over chained iterations."""
-    seed, k, cfg, paths = FAMILY_CASES[case]
+    seed, k, cfg = FAMILY_CASES[case]
     g, arrays, part = _setup(seed, provgen_like, CASES[0][2], k)
-    walks = scalar = 0
+    walks = 0
     for it in range(3):
         fld = extroversion_field(g, arrays, part, k, dense_ext_to=dense)
+        if case == "hub-guard":
+            fld = _jittered(fld, it)
         p_new, s_new = swap_iteration(
             g, part, fld, k, cfg, np.random.default_rng(0))
         p_ref, s_ref = swap_iteration_reference(
@@ -103,23 +117,23 @@ def test_family_paths_bit_identical(case, dense):
         assert (p_new == p_ref).all(), f"partition mismatch at iteration {it}"
         assert s_new == s_ref, f"stats mismatch at iteration {it}"
         walks += s_new.family_walks
-        scalar += s_new.family_scalar_walks
         if s_new.moves == 0:
             break
         part = p_new
-    if paths == "scalar":
-        assert 0 < scalar == walks
-    else:
-        assert 0 < scalar < walks
+    assert walks > 0
+    hubs = np.diff(g.row_ptr).max() > cfg.max_scan_neighbors
+    assert hubs == (case == "hub-guard")
 
 
-@pytest.mark.parametrize("cap", [512, 8])
-def test_scalar_family_helpers_match_numpy(cap):
-    """The scalar flood fill gives ``_family_of``'s members in its order, or
-    ``None`` where the fill reaches a hub; the scalar gains are the numpy
-    gains' float64 bits."""
-    g, arrays, part = _setup(7, provgen_like, CASES[0][2], 4)
-    fld = extroversion_field(g, arrays, part, 4)
+@pytest.mark.parametrize("cap", [512, 8, 3, 1])
+def test_scalar_family_helpers_match_reference(cap):
+    """The scalar flood fill gives the seed's family members in the seed's
+    order, hubs under the guard included, and the scalar gains toward each
+    destination and from home are the seed's receiver gain and sender loss,
+    bit for bit."""
+    k = 4
+    g, arrays, part = _setup(7, provgen_like, CASES[0][2], k)
+    fld = _jittered(extroversion_field(g, arrays, part, k), 0)
     cfg = SwapConfig(family_threshold=0.3, max_scan_neighbors=cap)
     rev = g.reverse_edge_index
     rel_mass_out = fld.edge_mass / np.maximum(fld.pr[g.src], 1e-30)
@@ -127,25 +141,28 @@ def test_scalar_family_helpers_match_numpy(cap):
     join_e = (part[g.src] == part[g.dst]) & (rel_rev > cfg.family_threshold)
     sym_mass = fld.edge_mass.astype(np.float64) + np.where(
         rev >= 0, fld.edge_mass[np.maximum(rev, 0)].astype(np.float64), 0.0)
-    rng = np.random.default_rng(1)
-    moved_b = bytearray(rng.random(g.n) < 0.1)
-    moved = np.frombuffer(moved_b, dtype=bool)
+    moved = np.random.default_rng(1).random(g.n) < 0.1
+    moved_b = bytearray(moved)
     rp, dl = g.row_ptr.tolist(), g.dst.tolist()
+    deg = np.diff(g.row_ptr)
     n_multi = n_hub = 0
     for v in np.nonzero(~moved)[0][:400].tolist():
         fam = _family_scalar(v, rp, dl, join_e.tobytes(), moved_b,
-                             cfg.family_max_size, cap)
-        fam_a = _family_of(g, v, moved, join_e, rel_mass_out, cfg)
-        if fam is None:
-            n_hub += 1
-            continue
-        assert fam == fam_a.tolist()
-        n_multi += len(fam) > 1
-        got = _family_gains_scalar(fam, rp, dl, part, sym_mass, 4)
-        want = _family_gains(g, fam_a, part, sym_mass, 4)
-        assert np.array(got).tobytes() == want.tobytes()
+                             rel_mass_out, cfg.family_max_size, cap)
+        assert fam == swap_ref._family_of(g, v, part, moved, rel_mass_out,
+                                          cfg)
+        gains = _family_gains_scalar(fam, rp, dl, part, sym_mass, k)
+        home = int(part[v])
+        for dest in range(k):
+            if dest != home:
+                gain, loss = swap_ref._family_gain(g, fam, dest, part,
+                                                   fld.edge_mass)
+                assert (gains[dest], gains[home]) == (gain, loss)
+        if len(fam) > 1:
+            n_multi += 1
+            n_hub += bool((deg[fam] > cap).any())
     assert n_multi > 0
-    assert (n_hub > 0) == (cap < np.diff(g.row_ptr).max())
+    assert (n_hub > 0) == (cap < deg.max())
 
 
 def test_reverse_edge_index_is_involution():

@@ -102,14 +102,12 @@ def test_walk_counters_agree(case):
             assert skipped == 0
             assert st.family_members == st.family_walks
         assert (st.family_s > 0) == (st.family_walks > 0)
-        assert st.family_scalar_walks <= st.family_walks
         assert st.moves == int((new != part).sum())
         walk = tracer.spans(name="swap.walk")[-1]["attrs"]
         assert walk == dict(singles_visited=st.singles_visited,
                             stale_rows=st.stale_rows,
                             family_walks=st.family_walks,
                             family_members=st.family_members,
-                            family_scalar_walks=st.family_scalar_walks,
                             family_s=st.family_s)
         totals["singles"] += st.singles_visited
         totals["stale"] += st.stale_rows

@@ -217,9 +217,12 @@ class Taper:
 
         return len(jax.devices())
 
-    def _seed_shard_order(self, part: np.ndarray) -> None:
-        """Resolve the sticky shard map now (idempotent) so the field memo
-        key is stable from the first evaluation on."""
+    def seed_shard_order(self, part: np.ndarray) -> None:
+        """Resolve the sticky shard map along ``part`` now (idempotent: a
+        map already installed stays), so the field memo key is stable from
+        the first evaluation on.  The field calls it with the partition it
+        evaluates; a caller whose first evaluation is of another partition
+        (a warm-up) calls it first with the one the shards should follow."""
         cfg = self.config
         if (cfg.field_backend != "pallas_sharded"
                 or cfg.shard_map_source == "stripe"
@@ -315,7 +318,7 @@ class Taper:
         cfg = self.config
         # resolve the sticky shard map before keying the memo, so the first
         # sharded evaluation doesn't memoize under a pre-install key
-        self._seed_shard_order(np.asarray(part))
+        self.seed_shard_order(np.asarray(part))
         # §4.2 lazy re-evaluation: if neither the trie probabilities nor the
         # partition changed since the last evaluation, the field is reused
         # verbatim instead of recomputed (workload drift without frequency
@@ -350,9 +353,12 @@ class Taper:
             )
             hs = self._pre.get("_halo_stats")
             if hs:
-                sp.set(halo_bytes_per_depth=hs.get("halo_bytes_per_depth", 0),
+                per_depth = hs.get("halo_bytes_per_depth", 0)
+                steps = hs.get("depth_steps", 0)
+                sp.set(halo_bytes_per_depth=per_depth,
                        halo_ratio=hs.get("halo_ratio", 0.0),
-                       depth_steps=hs.get("depth_steps", 0),
+                       depth_steps=steps,
+                       halo_bytes=per_depth * steps,
                        n_shards=hs.get("n_shards", 0))
         self._field_memo = (memo_key, fld)
         return fld

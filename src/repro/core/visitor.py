@@ -49,15 +49,18 @@ Either way each shard then advances its local destination blocks with the
 ``vm_step`` kernel, gathering sources from ``concat([beta_local,
 exchanged])`` via the packing's mode-matched source map (``src_map`` /
 ``src_map_sliced``), and per-slot edge masses accumulate shard-locally
-(over *all* edges, cut and local) and scatter back to raw edge order on
-the host at the end.
+(over *all* edges, cut and local).
 
 Because destination blocks never cross shards, the kernel's output rows
 are wholly shard-local and ``alpha`` assembles by concatenation — in
 *position* space; the shard map's inverse permutation restores vertex
-order (a no-op gather under the identity stripe map).  After graph
-mutations, stale device buffers re-upload per *dirty shard* (the packing's
-``shard_epoch`` counters), not wholesale.  Each sharded evaluation records
+order (a no-op gather under the identity stripe map).  The loop, that
+reorder, the scatter of slot masses into raw edge order (through the
+packing's ``slot_raw``, uploaded with the shard arrays) and the
+aggregates are one jitted program: no host round trip inside a call.
+After graph mutations, stale device buffers re-upload per *dirty shard*
+(the packing's ``shard_epoch`` counters), not wholesale.  Each sharded
+evaluation records
 its measured exchange footprint in ``pre["_halo_stats"]`` (bytes per depth
 step, halo ratio vs the full field, shard-map source, exchange backend)
 for serving metrics and benchmarks.
@@ -414,24 +417,35 @@ def _pallas_field(
 def _build_sharded_fn(mesh, trie: TrieArrays, depth_cap: int,
                       bps: int, block_n: int, block_e: int,
                       n_local_pad: int, exchange: str = "psum",
-                      n_shards: int = 1, round_cap: Tuple[int, ...] = ()):
-    """shard_map'd halo-exchange depth loop (see module docstring §sharded).
+                      n_shards: int = 1, round_cap: Tuple[int, ...] = (),
+                      *, n: int, m: int, k: int, dense_ext_to: bool = True,
+                      identity: bool = True):
+    """The whole sharded field as one jitted program (module docstring
+    §sharded): the ``shard_map``'d halo-exchange depth loop, the reorder of
+    ``alpha`` to vertex order, the slot-to-edge mass scatter and the
+    aggregates.  Nothing crosses to the host inside a call.
 
-    Static per (mesh, trie topology, packing shapes, exchange backend): the
-    trie topology and depth count bake into the loop; probabilities, the
-    partition vector and the packed shard arrays arrive as runtime inputs.
-    The ``exchange`` backend decides the per-depth collective: one ``psum``
-    of the union frontier (``fr_a``/``fr_b`` = the union owner maps), or
-    the two-tier sliced exchange — a small ``psum`` of the hot broadcast
-    rows (``fr_a``/``fr_b`` = the hot owner maps) plus ``S - 1`` ring
-    ``ppermute`` rounds of the cold per-shard-pair slices (``send`` = the
-    ``send_local`` tables, round ``r`` padded to the static
+    Static per (mesh, trie topology, packing shapes, exchange backend,
+    graph size): the trie topology and depth count bake into the loop;
+    probabilities, the partition vector and the packed shard arrays arrive
+    as runtime inputs.  The ``exchange`` backend decides the per-depth
+    collective: one ``psum`` of the union frontier (``fr_a``/``fr_b`` = the
+    union owner maps), or the two-tier sliced exchange — a small ``psum``
+    of the hot broadcast rows (``fr_a``/``fr_b`` = the hot owner maps) plus
+    ``S - 1`` ring ``ppermute`` rounds of the cold per-shard-pair slices
+    (``send`` = the ``send_local`` tables, round ``r`` padded to the static
     ``round_cap[r]``; ``src_map`` is then the packing's sliced variant).
     Each shard keeps its states feature-major (``(N, n_local_pad)``), the
     ``vm_step`` kernel's layout, so the exchanged rows of the module
     docstring travel as columns.
+
+    After the loop the shard outputs are gathered (``all-gather``) and the
+    rest runs alike on every device, so the program's only ``all-reduce``
+    and ``collective-permute`` ops are the halo exchange's.  The program is
+    named ``sharded_field_fn``: its XLA module name holds ``field_fn``, as
+    the fused jnp program's does.
     """
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.kernels.vm_step.kernel import vm_step_packed
 
@@ -440,6 +454,10 @@ def _build_sharded_fn(mesh, trie: TrieArrays, depth_cap: int,
     N = trie.n_nodes
     max_depth = min(trie.max_depth, depth_cap)
     sliced = exchange == "sliced"
+    counted = [
+        i for i in range(N)
+        if 1 <= int(depth[i]) < max_depth and not bool(trie.is_leaf[i])
+    ]
 
     def body(meta, src_map, dst_local, dst_label, inv_full, src_g, dst_g,
              vlab, fr_a, fr_b, send, part, p, lab_vcount, T, Tsum):
@@ -493,63 +511,150 @@ def _build_sharded_fn(mesh, trie: TrieArrays, depth_cap: int,
         return alpha_t.T[None], slot_mass[None]
 
     sharded = (P("model"),) * 11
-    fn = jax.shard_map(
+    loop = jax.shard_map(
         body, mesh=mesh,
         in_specs=sharded + (P(), P(), P(), P(), P()),
         out_specs=(P("model"), P("model")),
         check_vma=False,
     )
-    return jax.jit(fn)
+    everywhere = NamedSharding(mesh, P())
+
+    def gathered(x, shape):
+        return jax.lax.with_sharding_constraint(jnp.reshape(x, shape),
+                                                everywhere)
+
+    def sharded_field_fn(meta, src_map, dst_local, dst_label, inv_cnt, src_g,
+                         dst_g, vlab, slot_raw, fr_a, fr_b, send, pos, part,
+                         p, lab_vcount, T, Tsum):
+        alpha_sh, slot_mass = loop(
+            meta, src_map, dst_local, dst_label, inv_cnt, src_g, dst_g, vlab,
+            fr_a, fr_b, send, part, p, lab_vcount, T, Tsum)
+        alpha_pos = gathered(alpha_sh, (n_shards * n_local_pad, N))
+        # kernel rows are positions; the shard map's inverse restores
+        # vertex order (a slice under the identity stripe map)
+        alpha = alpha_pos[:n] if identity else alpha_pos[pos]
+        # the aggregates run over the slots, in slot order (padding slots
+        # carry no mass); the edge masses scatter into raw edge order
+        slots = (n_shards * inv_cnt.shape[1],)
+        slot_mass = gathered(slot_mass, slots)
+        src_g, dst_g, raw = (gathered(x, slots)
+                             for x in (src_g, dst_g, slot_raw))
+        mass = jnp.zeros((m,), jnp.float32).at[
+            jnp.where(raw < 0, m, raw)].set(slot_mass, mode="drop")
+        local = (part[src_g] == part[dst_g]).astype(jnp.float32)
+        out = _field_aggregates(counted, k, dense_ext_to, alpha, slot_mass,
+                                src_g, dst_g, part, local, n)
+        return (out[0], out[1], mass) + out[3:]
+
+    return jax.jit(sharded_field_fn)
 
 
-def _sharded_device_arrays(sp, pre: Dict) -> Dict:
+def _field_mesh(pre: Dict):
+    """The sharded field's mesh: ``pre["_mesh"]`` when a caller seeded one,
+    else ``repro.launch.mesh.make_smoke_mesh()`` over every visible device,
+    cached there."""
+    mesh = pre.get("_mesh")
+    if mesh is None:
+        from repro.launch.mesh import make_smoke_mesh
+
+        mesh = make_smoke_mesh()
+        pre["_mesh"] = mesh
+    return mesh
+
+
+def _put_rows(arr, host: np.ndarray, rows) -> object:
+    """``arr`` (sharded along its leading axis) with the leading-axis rows
+    in ``rows`` replaced by those of ``host``: only those rows' buffers
+    are uploaded, every other device buffer is kept as it is."""
+    bufs = []
+    for shard in arr.addressable_shards:
+        lo = shard.index[0].start or 0
+        hi = shard.index[0].stop if shard.index[0].stop is not None \
+            else host.shape[0]
+        if any(lo <= r < hi for r in rows):
+            bufs.append(jax.device_put(host[lo:hi], shard.device))
+        else:
+            bufs.append(shard.data)
+    return jax.make_array_from_single_device_arrays(
+        arr.shape, arr.sharding, bufs)
+
+
+def _sharded_device_arrays(sp, pre: Dict, mesh=None) -> Dict:
     """Device-resident stacked shard arrays, re-uploaded per dirty shard.
 
-    The packing's ``shard_epoch`` counters say which shard slices changed
-    since this cache last uploaded them; only those rows move to the device
-    (plus the small frontier maps when ``fr_epoch`` moved).  Upload counts
-    accumulate in ``pre["_shard_uploads"]`` for benchmarks/tests.
+    Each ``(S, ...)`` array is placed on the field's mesh, one shard slice
+    per device along ``model`` (the shard map's inverse permutation
+    ``pos`` is replicated).  The packing's ``shard_epoch`` counters say
+    which shard slices changed since this cache last uploaded them; only
+    those rows move to the device (plus the small frontier maps when
+    ``fr_epoch`` moved).  ``raw_epoch`` says which shards' ``slot_raw``
+    followed an edge renumbering; that row alone re-uploads.  Upload counts
+    accumulate in ``pre["_shard_uploads"]`` (``last_shards``: shard slices
+    uploaded by the latest sync) for benchmarks/tests.
     """
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    mesh = _field_mesh(pre) if mesh is None else mesh
+    by_shard = NamedSharding(mesh, P("model"))
+    everywhere = NamedSharding(mesh, P())
     stats = pre.setdefault(
         "_shard_uploads", {"last_shards": 0, "total_shards": 0, "rebuilds": 0})
     names = ("meta", "src_map", "src_map_sliced", "dst_local", "dst_label",
              "inv_cnt", "src_global", "dst_global", "vlabels", "send_local")
+
+    def host(nm):
+        a = getattr(sp, nm)
+        return a.astype(np.int32) if nm == "slot_raw" else a
+
+    def put(a):
+        return jax.device_put(a, by_shard)
+
+    def pos():
+        return (None if sp.identity
+                else jax.device_put(sp.pos_of.astype(np.int32), everywhere))
+
     sdev = pre.get("_shard_dev")
-    if sdev is not None and sdev["sp"] is not sp:
-        sdev = None  # packing was rebuilt from scratch (capacity overflow)
+    if sdev is not None and (sdev["sp"] is not sp or sdev["mesh"] is not mesh):
+        sdev = None  # packing rebuilt from scratch (capacity overflow, a
+        #              re-deal) or another mesh
     if sdev is None:
-        sdev = {"sp": sp,
+        sdev = {"sp": sp, "mesh": mesh,
                 "epochs": sp.shard_epoch.copy(),
+                "raw_epochs": sp.raw_epoch.copy(),
                 "fr_epoch": sp.fr_epoch,
-                "arrays": {nm: jnp.asarray(getattr(sp, nm)) for nm in names},
-                "fr": (jnp.asarray(sp.fr_local_idx),
-                       jnp.asarray(sp.fr_owned)),
-                "hot": (jnp.asarray(sp.hot_local_idx),
-                        jnp.asarray(sp.hot_owned)),
+                "version": sp.version,
+                "arrays": {nm: put(host(nm)) for nm in names + ("slot_raw",)},
+                "fr": (put(sp.fr_local_idx), put(sp.fr_owned)),
+                "hot": (put(sp.hot_local_idx), put(sp.hot_owned)),
                 "n_pos": sp.pos_of.shape[0],
-                "pos": (None if sp.identity
-                        else jnp.asarray(sp.pos_of.astype(np.int32)))}
+                "pos": pos()}
         pre["_shard_dev"] = sdev
         stats["last_shards"] = sp.n_shards
         stats["total_shards"] += sp.n_shards
         stats["rebuilds"] += 1
         return sdev
-    dirty = np.nonzero(sp.shard_epoch != sdev["epochs"])[0]
-    for s in dirty.tolist():
+    dirty = np.nonzero(sp.shard_epoch != sdev["epochs"])[0].tolist()
+    raw_dirty = np.nonzero(sp.raw_epoch != sdev["raw_epochs"])[0].tolist()
+    arrays = sdev["arrays"]
+    if dirty:
         for nm in names:
-            sdev["arrays"][nm] = sdev["arrays"][nm].at[s].set(
-                jnp.asarray(getattr(sp, nm)[s]))
+            arrays[nm] = _put_rows(arrays[nm], host(nm), dirty)
+    if dirty or raw_dirty:
+        arrays["slot_raw"] = _put_rows(arrays["slot_raw"], host("slot_raw"),
+                                       sorted(set(dirty) | set(raw_dirty)))
     if sp.fr_epoch != sdev["fr_epoch"]:
-        sdev["fr"] = (jnp.asarray(sp.fr_local_idx), jnp.asarray(sp.fr_owned))
+        sdev["fr"] = (put(sp.fr_local_idx), put(sp.fr_owned))
         sdev["fr_epoch"] = sp.fr_epoch
     if sp.pos_of.shape[0] != sdev["n_pos"]:
         # vertex growth extended the shard map's identity tail
         sdev["n_pos"] = sp.pos_of.shape[0]
-        sdev["pos"] = (None if sp.identity
-                       else jnp.asarray(sp.pos_of.astype(np.int32)))
+        sdev["pos"] = pos()
     sdev["epochs"] = sp.shard_epoch.copy()
-    stats["last_shards"] = int(dirty.size)
-    stats["total_shards"] += int(dirty.size)
+    sdev["raw_epochs"] = sp.raw_epoch.copy()
+    sdev["version"] = sp.version
+    uploaded = len(set(dirty) | set(raw_dirty))
+    stats["last_shards"] = uploaded
+    stats["total_shards"] += uploaded
     return sdev
 
 
@@ -564,10 +669,13 @@ def _pallas_sharded_field(
     mesh=None,
     shard_map_source: str = "stripe",
     halo_exchange: str = "sliced",
+    parent: Span = NOOP_SPAN,
 ):
     """Multi-device extroversion field: ``vm_step`` per shard over the
     graph's sharded packing, halo-exchanging only the ``beta`` rows other
-    shards read between depth steps (module docstring §sharded).
+    shards read between depth steps (module docstring §sharded).  One
+    jitted program (:func:`_build_sharded_fn`) per call; its outputs stay
+    on the device.
 
     The mesh defaults to ``repro.launch.mesh.make_smoke_mesh()`` over every
     visible device and is cached in ``pre["_mesh"]``; callers may seed
@@ -580,30 +688,25 @@ def _pallas_sharded_field(
     never re-dealt mid-invocation.  ``Taper.maybe_redeal_shards`` (called
     by ``OnlineTaper.commit_invocation``) refreshes it off the critical
     path.  Callers may seed ``pre["_shard_order"] = (token, pos_of)``
-    directly (tests use random permutations).
+    directly (``Taper.seed_shard_order`` does, from a given partition;
+    tests use random permutations).
+
+    When the packing or its device copy has to change (first call, a
+    mutation, a re-deal), ``parent`` receives a ``field.shard_pack`` child
+    around the packing and the upload, with ``rebuilt`` (1 when the device
+    copy was rebuilt whole) and ``shards_uploaded``.
     """
     from repro.graphs.sharded_packing import compute_shard_order
+    from repro.kernels.vm_step.kernel import MAX_EDGE_BLOCKS
 
-    if mesh is None:
-        mesh = pre.get("_mesh")
-    if mesh is None:
-        from repro.launch.mesh import make_smoke_mesh
-
-        mesh = make_smoke_mesh()
-        pre["_mesh"] = mesh
+    mesh = _field_mesh(pre) if mesh is None else mesh
     S = int(mesh.shape["model"])
 
     n, m = g.n, g.m
     N = trie.n_nodes
-    cnt = pre.get("cnt")
-    if cnt is None:
-        # the graph's own (incrementally patched) matrix, so the cached
-        # sharded packing stays patchable across mutations
-        cnt = g.cached_neighbor_label_counts()
     lab_vcount = pre.get("lab_vcount")
     if lab_vcount is None:
         lab_vcount = g.label_counts()
-    dev = _device_inputs(g, pre, cnt, lab_vcount)
 
     order_entry = pre.get("_shard_order")
     if order_entry is None and shard_map_source != "stripe":
@@ -611,31 +714,46 @@ def _pallas_sharded_field(
                        compute_shard_order(g, shard_map_source, S, part=part))
         pre["_shard_order"] = order_entry
     token, order = order_entry if order_entry is not None else ("stripe", None)
-    sp = g.vm_packing_sharded(S, cnt=cnt, order=order, order_token=token)
-    sdev = _sharded_device_arrays(sp, pre)
-    arr = sdev["arrays"]
-    frloc, frown = sdev["fr"]
 
-    T_key = (trie.topology_signature(), int(depth_cap), trie.cond_p.tobytes())
-    t_hit = pre.get("_T_dev")
-    if t_hit is None or t_hit[0] != T_key:
-        T = jnp.asarray(_capped_transition(trie, depth_cap))
-        Tsum = T.sum(axis=2)
-        pre["_T_dev"] = (T_key, T, Tsum)
+    sdev = pre.get("_shard_dev")
+    if (sdev is not None and sdev["version"] == g.version
+            and sdev["sp"].order_token == token and sdev["mesh"] is mesh):
+        sp = sdev["sp"]
     else:
-        _, T, Tsum = t_hit
+        with parent.child("field.shard_pack") as pack:
+            rebuilds = pre.get("_shard_uploads", {}).get("rebuilds", 0)
+            cnt = pre.get("cnt")
+            if cnt is None:
+                # the graph's own (incrementally patched) matrix, so the
+                # cached sharded packing stays patchable across mutations
+                cnt = g.cached_neighbor_label_counts()
+            sp = g.vm_packing_sharded(S, cnt=cnt, order=order,
+                                      order_token=token)
+            if sp.eb_cap > MAX_EDGE_BLOCKS:
+                raise ValueError(
+                    f"a shard of the sharded field needs {sp.eb_cap} edge "
+                    f"blocks, more than the vm_step kernel's block table "
+                    f"holds ({MAX_EDGE_BLOCKS}); use more shards")
+            sdev = _sharded_device_arrays(sp, pre, mesh)
+            ups = pre["_shard_uploads"]
+            pack.set(rebuilt=int(ups["rebuilds"] != rebuilds),
+                     shards_uploaded=int(ups["last_shards"]))
+    arr = sdev["arrays"]
 
+    T = _capped_transition(trie, depth_cap)
+    Tsum = T.sum(axis=2)
     round_cap = tuple(int(c) for c in sp.round_cap)
     key = ("sharded", trie.topology_signature(), int(depth_cap), S,
            sp.blocks_per_shard, sp.block_n, sp.block_e, sp.eb_cap,
            sp.n_local_pad, sp.h_pad, sp.hot_pad, round_cap, halo_exchange,
-           id(mesh))
+           id(mesh), n, m, k, dense_ext_to, sp.identity)
     fn = _FIELD_CACHE.get(key)
     if fn is None:
         fn = _build_sharded_fn(
             mesh, trie, depth_cap, sp.blocks_per_shard, sp.block_n,
             sp.block_e, sp.n_local_pad, exchange=halo_exchange,
-            n_shards=S, round_cap=round_cap)
+            n_shards=S, round_cap=round_cap, n=n, m=m, k=k,
+            dense_ext_to=dense_ext_to, identity=sp.identity)
         while len(_FIELD_CACHE) >= 64:
             _FIELD_CACHE.pop(next(iter(_FIELD_CACHE)))
         _FIELD_CACHE[key] = fn
@@ -644,23 +762,12 @@ def _pallas_sharded_field(
         src_map_in = arr["src_map_sliced"]
         fr_a, fr_b = sdev["hot"]
     else:
-        src_map_in, fr_a, fr_b = arr["src_map"], frloc, frown
-    part_dev = jnp.asarray(part.astype(np.int32))
-    alpha_sh, slot_mass = fn(
-        arr["meta"], src_map_in, arr["dst_local"], arr["dst_label"],
-        arr["inv_cnt"], arr["src_global"], arr["dst_global"], arr["vlabels"],
-        fr_a, fr_b, arr["send_local"],
-        part_dev, jnp.asarray(trie.p),
-        dev["lab_vcount"], T, Tsum)
-
-    alpha_pos = jnp.reshape(alpha_sh, (S * sp.n_local_pad, N))
-    # kernel rows are positions; the shard map's inverse restores vertex
-    # order (no-op slice under the identity stripe map)
-    alpha = (alpha_pos[:n] if sdev["pos"] is None
-             else alpha_pos[sdev["pos"]])
-    mass = jnp.asarray(sp.scatter_slot_values(np.asarray(slot_mass), m))
-    src, dst = dev["src"], dev["dst"]
-    local = (part_dev[src] == part_dev[dst]).astype(jnp.float32)
+        src_map_in, (fr_a, fr_b) = arr["src_map"], sdev["fr"]
+    out = fn(arr["meta"], src_map_in, arr["dst_local"], arr["dst_label"],
+             arr["inv_cnt"], arr["src_global"], arr["dst_global"],
+             arr["vlabels"], arr["slot_raw"], fr_a, fr_b, arr["send_local"],
+             sdev["pos"], part.astype(np.int32), np.asarray(trie.p),
+             np.asarray(lab_vcount), T, Tsum)
 
     full = sp.full_field_bytes_per_depth(n, N)
     halo = sp.halo_bytes_per_depth(N, exchange=halo_exchange)
@@ -672,22 +779,16 @@ def _pallas_sharded_field(
         "shard_map_source": token.split(":")[0],
         "halo_exchange": halo_exchange,
         "n_shards": S,
-        # devices the kernel's output actually spans (a mesh of one device
+        # devices the field program's outputs span (a mesh of one device
         # repeated, or inputs pinned to one chip, would show here)
-        "n_devices": len(alpha_sh.sharding.device_set),
+        "n_devices": len(out[0].sharding.device_set),
         "n_frontier": sp.n_frontier,
         "hot_rows": sp.hot_pad,
         "sliced_rows": sp.hot_pad + int(sp.round_cap[1:].sum()),
         # DP depth steps the kernel ran (each one is a halo exchange)
         "depth_steps": max(int(max_depth) - 1, 0),
     }
-
-    counted = [
-        i for i in range(N)
-        if 1 <= int(trie.depth[i]) < max_depth and not bool(trie.is_leaf[i])
-    ]
-    return _field_aggregates(counted, k, dense_ext_to,
-                             alpha, mass, src, dst, part_dev, local, n)
+    return out
 
 
 def extroversion_field(
@@ -743,7 +844,9 @@ def extroversion_field(
         out = _pallas_sharded_field(g, trie, part, k, depth_cap, pre,
                                     dense_ext_to,
                                     shard_map_source=shard_map_source,
-                                    halo_exchange=halo_exchange)
+                                    halo_exchange=halo_exchange,
+                                    parent=NOOP_SPAN if parent is None
+                                    else parent)
     elif backend == "jnp":
         key = (trie.topology_signature(), k, depth_cap, g.n, g.m, fused,
                dense_ext_to)

@@ -286,9 +286,11 @@ class LabelledGraph:
         self.src = np.asarray(self.src, dtype=np.int32)
         self.dst = np.asarray(self.dst, dtype=np.int32)
         if self.row_ptr is None:
-            order = np.lexsort((self.dst, self.src))
-            self.src = self.src[order]
-            self.dst = self.dst[order]
+            keys = self.src.astype(np.int64) * max(self.n, 1) + self.dst
+            if not (keys[1:] >= keys[:-1]).all():
+                order = np.lexsort((self.dst, self.src))
+                self.src = self.src[order]
+                self.dst = self.dst[order]
             counts = np.bincount(self.src, minlength=self.n)
             self.row_ptr = np.concatenate([[0], np.cumsum(counts)]).astype(np.int64)
 
@@ -309,9 +311,13 @@ class LabelledGraph:
         edges = edges[keep]
         sym = np.concatenate([edges, edges[:, ::-1]], axis=0)
         if dedup and len(sym):
-            key = sym[:, 0] * np.int64(n) + sym[:, 1]
-            _, idx = np.unique(key, return_index=True)
-            sym = sym[idx]
+            # the sorted distinct (src, dst) keys: the edge list in its
+            # final order, with no sort left for the constructor
+            key = np.sort(sym[:, 0] * np.int64(n) + sym[:, 1])
+            key = key[np.concatenate([[True], key[1:] != key[:-1]])]
+            src, dst = key // n, key % n
+        else:
+            src, dst = sym[:, 0], sym[:, 1]
         labels = np.asarray(labels, dtype=np.int32)
         if label_names is None:
             label_names = [f"L{i}" for i in range(int(labels.max(initial=-1)) + 1)]
@@ -319,8 +325,8 @@ class LabelledGraph:
             n=n,
             labels=labels,
             label_names=list(label_names),
-            src=sym[:, 0].astype(np.int32),
-            dst=sym[:, 1].astype(np.int32),
+            src=src.astype(np.int32),
+            dst=dst.astype(np.int32),
         )
 
     def copy(self) -> "LabelledGraph":
@@ -382,6 +388,16 @@ class LabelledGraph:
         if self._rev_index is None:
             keys = self.src.astype(np.int64) * self.n + self.dst
             rkeys = self.dst.astype(np.int64) * self.n + self.src
+            # a symmetric graph's reverse keys are a permutation of its
+            # (sorted, distinct) keys: the permutation that sorts them is
+            # the inverse of the reverse index, one sort instead of m
+            # binary searches
+            order = np.argsort(rkeys)
+            if np.array_equal(rkeys[order], keys):
+                rev = np.empty(self.m, dtype=np.int64)
+                rev[order] = np.arange(self.m, dtype=np.int64)
+                self._rev_index = rev
+                return rev
             pos = np.searchsorted(keys, rkeys)
             pos = np.minimum(pos, max(self.m - 1, 0))
             found = (keys[pos] == rkeys) if self.m else np.zeros(0, bool)

@@ -66,7 +66,8 @@ to the compute layout.
   round_base[(reader - owner) % S] + fr_slot``, each round padded to its
   own ``round_cap``).
 * ``slot_raw`` — packed slot -> raw edge id, so per-slot edge masses scatter
-  back into the graph's raw edge order on the host.
+  back into the graph's raw edge order on the device, inside the field's
+  one jitted program.
 
 Like :meth:`LabelledGraph.vm_packing`, the packing is partition-independent
 *given a shard map* and version-keyed.  After
@@ -77,7 +78,10 @@ positions are *appended* to the frontier and to the pair lists (existing
 slots stay valid, so unaffected shards' maps survive untouched; owners whose
 send tables grew bump their epoch), brand-new vertices extend the shard map
 with an identity tail, and per-shard ``shard_epoch`` counters tell
-device-buffer caches exactly which shard slices to re-upload.  Capacity
+device-buffer caches exactly which shard slices to re-upload.  A mutation
+also renumbers the graph's raw edges, which moves ``slot_raw`` in shards
+whose structure did not change; ``raw_epoch`` counts those, so a device
+copy of ``slot_raw`` alone follows them.  Capacity
 headroom (``EB_SLACK`` spare edge blocks per shard, ``FR_SLACK`` spare
 frontier rows, ``PAIR_SLACK`` spare pair-list slots) absorbs modest growth
 without a shape change; overflowing it evicts the entry for a scratch
@@ -286,11 +290,14 @@ class ShardedVMPacking:
     hot_local_idx: np.ndarray = field(default=None)  # (S, hot_pad) int32
     hot_owned: np.ndarray = field(default=None)      # (S, hot_pad) f32
     shard_epoch: np.ndarray = field(default=None)  # (S,) int64 change counters
+    raw_epoch: np.ndarray = field(default=None)    # (S,) int64 slot_raw renumbers
     fr_epoch: int = 0
 
     def __post_init__(self):
         if self.shard_epoch is None:
             self.shard_epoch = np.zeros(self.n_shards, dtype=np.int64)
+        if self.raw_epoch is None:
+            self.raw_epoch = np.zeros(self.n_shards, dtype=np.int64)
 
     @property
     def e_pad(self) -> int:
@@ -354,17 +361,6 @@ class ShardedVMPacking:
             if self.n_shards else 0,
         }
 
-    def scatter_slot_values(self, values: np.ndarray, m: int,
-                            dtype=np.float32) -> np.ndarray:
-        """Scatter per-slot values (flattened ``(S * e_pad,)`` or
-        ``(S, e_pad)``) back into raw edge order."""
-        flat = np.asarray(values).reshape(-1)
-        raw = self.slot_raw.reshape(-1)
-        ok = raw >= 0
-        out = np.zeros(m, dtype=dtype)
-        out[raw[ok]] = flat[ok]
-        return out
-
 
 def _dst_sorted_view(
         g, sp: Optional[ShardedVMPacking] = None,
@@ -387,7 +383,9 @@ def _dst_sorted_view(
         d = g.dst[order]
         return g.src[order], d, d, order
     dpos = pos_of[g.dst]
-    order = np.lexsort((g.src, dpos))
+    # (dpos, src) pairs are unique, so one unstable sort of the combined
+    # key orders them as a two-key lexsort would, at a fraction of its cost
+    order = np.argsort(dpos * np.int64(max(g.n, 1)) + g.src)
     return g.src[order], g.dst[order], dpos[order], order
 
 
@@ -426,24 +424,23 @@ def _fill_shard(sp: ShardedVMPacking, s: int, g, cnt,
 
     eb_off = np.concatenate([[0], np.cumsum(eb_need)])
     labels = g.labels
-    for i, b in enumerate(blocks.tolist()):
-        lo, hi = int(lo_all[i]), int(hi_all[i])
-        c = hi - lo
-        o = int(eb_off[i]) * be
-        if c:
-            es = e_src[lo:hi]
-            ed = e_dst[lo:hi]
-            sp.src_global[s, o:o + c] = es
-            sp.dst_local[s, o:o + c] = e_dpos[lo:hi] - b * bn
-            sp.dst_global[s, o:o + c] = ed
-            dl = labels[ed]
-            sp.dst_label[s, o:o + c] = dl
-            sp.inv_cnt[s, o:o + c] = 1.0 / np.maximum(
-                cnt[es, dl].astype(np.float32), 1.0)
-            sp.slot_raw[s, o:o + c] = e_raw[lo:hi]
-        blk_meta = sp.meta[s, eb_off[i]:eb_off[i + 1]]
-        blk_meta[:, 0] = i              # local destination block id
-        blk_meta[0, 1] = 1              # first edge block zero-inits output
+    # the shard's edges are one contiguous run of the dst-sorted view; each
+    # lands at its block's first slot plus its rank inside the block
+    lo, hi = int(lo_all[0]), int(hi_all[-1])
+    blk = np.repeat(np.arange(bps, dtype=np.int64), cnt_b)
+    slot = eb_off[blk] * be + np.arange(lo, hi, dtype=np.int64) - lo_all[blk]
+    es, ed = e_src[lo:hi], e_dst[lo:hi]
+    dl = labels[ed]
+    sp.src_global[s, slot] = es
+    sp.dst_local[s, slot] = e_dpos[lo:hi] - blocks[blk] * bn
+    sp.dst_global[s, slot] = ed
+    sp.dst_label[s, slot] = dl
+    sp.inv_cnt[s, slot] = 1.0 / np.maximum(cnt[es, dl].astype(np.float32), 1.0)
+    sp.slot_raw[s, slot] = e_raw[lo:hi]
+    # local destination block id per edge block; the first edge block of
+    # each destination block zero-inits its output
+    sp.meta[s, : eb_off[-1], 0] = np.repeat(np.arange(bps), eb_need)
+    sp.meta[s, eb_off[:-1], 1] = 1
 
     # owned labels (pad rows beyond n get -1, which never matches a prior)
     plo, phi = s * sp.n_local_pad, min((s + 1) * sp.n_local_pad, g.n)
@@ -506,16 +503,15 @@ def _remap_shard_src(sp: ShardedVMPacking, s: int) -> None:
     ``src_map_sliced`` indexes ``[local | hot union | (owner, pair slot)
     receive buffer]`` (two-tier all_to_all exchange).  Every halo source
     must already be marked in ``fr_reads[s]`` (:func:`_mark_reads`)."""
-    fr = sp.frontier[: sp.n_frontier]
-    order = np.argsort(fr, kind="stable")
-    fr_sorted = fr[order]
     sg = sp.src_global[s].astype(np.int64)
     spos = sp.pos_of[sg]
     owned = (spos >= s * sp.n_local_pad) & (spos < (s + 1) * sp.n_local_pad)
     real = sp.slot_raw[s] >= 0
-    pos = np.searchsorted(fr_sorted, spos)
-    pos = np.minimum(pos, max(sp.n_frontier - 1, 0))
-    fr_idx = order[pos] if sp.n_frontier else np.zeros_like(pos)
+    # frontier index of each live frontier position (a table lookup per
+    # slot; a live position appears at most once in the frontier)
+    fr_of_pos = np.zeros(sp.pos_of.shape[0], dtype=np.int64)
+    fr_of_pos[sp.frontier[: sp.n_frontier]] = np.arange(sp.n_frontier)
+    fr_idx = fr_of_pos[spos]
     local = spos - s * sp.n_local_pad
     remapped = np.where(owned, local, sp.n_local_pad + fr_idx)
     sp.src_map[s] = np.where(real, remapped, 0).astype(np.int32)
@@ -731,10 +727,15 @@ def patch_sharded_vm_packing(sp: ShardedVMPacking, g, cnt: np.ndarray,
         sp.vtx_at = np.concatenate([sp.vtx_at, tail])
 
     # every shard's slot -> raw-edge map must follow the global edge
-    # renumbering (host-side only — device buffers never hold slot_raw,
-    # so this re-indexing does not dirty any shard's upload epoch)
-    ok = sp.slot_raw >= 0
-    sp.slot_raw[ok] = old2new[sp.slot_raw[ok]]
+    # renumbering; that moves no structure, so it bumps ``raw_epoch`` (the
+    # device copy of ``slot_raw`` alone re-uploads), not ``shard_epoch``
+    for s in range(S):
+        row = sp.slot_raw[s]
+        ok = row >= 0
+        new = old2new[row[ok]]
+        if not np.array_equal(new, row[ok]):
+            row[ok] = new
+            sp.raw_epoch[s] += 1
     aff_blocks = np.unique(np.concatenate([
         sp.pos_of[np.asarray(changed_dsts, dtype=np.int64)] // bn,
         np.arange(nb_old, nb_new, dtype=np.int64),

@@ -429,3 +429,186 @@ def test_smoke_mesh_matches_device_count():
 
     mesh = make_smoke_mesh()
     assert int(mesh.shape["model"]) == _n_devices()
+
+
+# ---------------------------------------------------------------------------
+# the one-program sharded field: float64 reference, device-side scatter
+# ---------------------------------------------------------------------------
+
+MQ = [("Area.Artist.(Artist|Label).Area", 0.2),
+      ("Artist.Credit.(Track|Recording).Credit.Artist", 0.3),
+      ("Artist.Credit.Track.Medium", 0.5)]
+
+
+def _bench_reference():
+    """``bench/reference.py``: the benchmark's float64 field, in numpy
+    alone (imports nothing of the program)."""
+    import importlib.util
+    from pathlib import Path
+
+    path = Path(__file__).resolve().parents[1] / "bench" / "reference.py"
+    spec = importlib.util.spec_from_file_location("bench_reference", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("source", ["stripe", "partition"])
+def test_one_program_field_matches_float64_reference_and_jnp(source):
+    ref_mod = _bench_reference()
+    g = musicbrainz_like(2500, seed=51)
+    arrays = _trie(g, [(parse_rpq(q), f) for q, f in MQ])
+    k = 8
+    part = hash_partition(g.n, k, seed=1)
+    sh = extroversion_field(g, arrays, part, k, backend="pallas_sharded",
+                            shard_map_source=source)
+    _assert_field_parity(
+        extroversion_field(g, arrays, part, k, backend="jnp"), sh)
+    up = g.src < g.dst
+    ref = ref_mod.Ref(g.n, g.labels, np.stack([g.src[up], g.dst[up]], 1),
+                      g.label_names)
+    want = ref.field(part, [(q, f, 3) for q, f in MQ], k)
+    errs = ref_mod.field_errors(
+        {"pr": sh.pr, "extroversion": sh.extroversion, "ext_to": sh.ext_to,
+         "total": sh.total_extroversion}, want)
+    # float32 against float64 at this size reads about 1e-6
+    assert max(errs.values()) < 2e-5, errs
+
+
+def test_one_program_field_leaves_nothing_on_the_host():
+    """A call of the sharded backend dispatches its jitted program and
+    returns device arrays: no device-to-host copy happens inside it."""
+    import jax
+
+    from repro.core.visitor import _pallas_sharded_field
+
+    g = musicbrainz_like(900, seed=52)
+    arrays = _trie(g)
+    part = hash_partition(g.n, 4, seed=2)
+    pre = {}
+    _pallas_sharded_field(g, arrays, part, 4, arrays.max_depth, pre, True)
+    with jax.transfer_guard_device_to_host("disallow"):
+        out = _pallas_sharded_field(g, arrays, part, 4, arrays.max_depth,
+                                    pre, True)
+        assert all(isinstance(a, jax.Array) for a in out)
+    assert len(out) == 6
+
+
+def test_one_program_field_module_is_named_field_fn():
+    from repro.core.visitor import _FIELD_CACHE
+
+    g = musicbrainz_like(700, seed=53)
+    arrays = _trie(g)
+    part = hash_partition(g.n, 4, seed=3)
+    extroversion_field(g, arrays, part, 4, backend="pallas_sharded")
+    fns = [f for key, f in _FIELD_CACHE.items() if key[0] == "sharded"]
+    assert fns
+    assert all("field_fn" in f.__name__ for f in fns)
+
+
+def test_shard_over_the_kernel_block_table_raises(monkeypatch):
+    from repro.kernels.vm_step import kernel
+
+    g = musicbrainz_like(700, seed=54)
+    arrays = _trie(g)
+    part = hash_partition(g.n, 4, seed=4)
+    monkeypatch.setattr(kernel, "MAX_EDGE_BLOCKS", 2)
+    with pytest.raises(ValueError, match="edge blocks"):
+        extroversion_field(g, arrays, part, 4, _precomputed={},
+                           backend="pallas_sharded")
+
+
+def test_device_slot_scatter_follows_dirty_shard_patches():
+    """After mutations the device copy of ``slot_raw`` equals the patched
+    packing's (the edge renumbering re-uploads it, a structural change
+    re-uploads the dirty shard), and the device-side scatter puts every
+    edge mass where the jnp field puts it."""
+    g = musicbrainz_like(1600, seed=55)
+    arrays = _trie(g)
+    part = hash_partition(g.n, 4, seed=5)
+    pre = {}
+    extroversion_field(g, arrays, part, 4, _precomputed=pre,
+                       backend="pallas_sharded")
+    sdev = pre["_shard_dev"]
+    sp = sdev["sp"]
+    raw0 = sp.raw_epoch.copy()
+    # edges among the lowest ids: they sort first, so every later raw id
+    # moves, and they land in the first destination block
+    g.apply_mutations(MutationBatch(add_edges=[(3, 17), (5, 29)]))
+    assert g.vm_packing_sharded(sp.n_shards) is sp
+    assert (sp.raw_epoch != raw0).all()
+    sh = extroversion_field(g, arrays, part, 4, _precomputed=pre,
+                            backend="pallas_sharded")
+    assert pre["_shard_dev"] is sdev and pre["_shard_uploads"]["rebuilds"] == 1
+    assert np.array_equal(np.asarray(sdev["arrays"]["slot_raw"]),
+                          sp.slot_raw.astype(np.int32))
+    ref = extroversion_field(g, arrays, part, 4, backend="jnp")
+    _assert_field_parity(ref, sh)
+
+
+_FOUR_DEVICE_FIELD = """
+import numpy as np
+from repro.core.rpq import parse_rpq
+from repro.core.tpstry import TPSTry
+from repro.core.visitor import extroversion_field
+from repro.graphs.generators import musicbrainz_like
+from repro.graphs.graph import MutationBatch
+from repro.graphs.partition import hash_partition
+
+MQ = %r
+g = musicbrainz_like(3000, seed=56)
+arrays = TPSTry.from_workload(
+    [(parse_rpq(q), f) for q, f in MQ]).compile(g.label_names)
+part = hash_partition(g.n, 8, seed=1)
+pre = {}
+sh = extroversion_field(g, arrays, part, 8, _precomputed=pre,
+                        backend="pallas_sharded", shard_map_source=%r)
+assert pre["_halo_stats"]["n_devices"] == 4, pre["_halo_stats"]
+ref = extroversion_field(g, arrays, part, 8, backend="jnp")
+for f in ("alpha", "pr", "edge_mass", "extro_mass", "extroversion", "ext_to"):
+    np.testing.assert_allclose(getattr(sh, f), getattr(ref, f),
+                               rtol=1e-4, atol=2e-5, err_msg=f)
+if %r == "stripe":
+    # a mutation among the lowest ids: shard 0 changes, every shard's raw
+    # edge ids move; the device copy follows without a rebuild
+    sdev = pre["_shard_dev"]
+    sp = sdev["sp"]
+    epochs, raws = sp.shard_epoch.copy(), sp.raw_epoch.copy()
+    g.apply_mutations(MutationBatch(add_edges=[(3, 17), (5, 29)]))
+    assert g.vm_packing_sharded(4) is sp
+    assert list(np.nonzero(sp.shard_epoch != epochs)[0]) == [0]
+    assert (sp.raw_epoch != raws).all()
+    sh = extroversion_field(g, arrays, part, 8, _precomputed=pre,
+                            backend="pallas_sharded")
+    assert pre["_shard_dev"] is sdev
+    assert pre["_shard_uploads"]["rebuilds"] == 1
+    assert pre["_shard_uploads"]["last_shards"] == 4
+    assert np.array_equal(np.asarray(sdev["arrays"]["slot_raw"]),
+                          sp.slot_raw.astype(np.int32))
+    ref = extroversion_field(g, arrays, part, 8, backend="jnp")
+    for f in ("pr", "edge_mass", "extroversion", "ext_to"):
+        np.testing.assert_allclose(getattr(sh, f), getattr(ref, f),
+                                   rtol=1e-4, atol=2e-5, err_msg=f)
+print("ok")
+"""
+
+
+@pytest.mark.parametrize("source", ["stripe", "partition"])
+def test_one_program_field_on_four_forced_host_devices(source):
+    """The one-program field over a four-device mesh (a child process: this
+    one has a single CPU device) against the jnp field, all six outputs."""
+    import os
+    import subprocess
+    import sys
+
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.pathsep.join(
+                   [src, os.environ.get("PYTHONPATH", "")]))
+    r = subprocess.run([sys.executable, "-c",
+                        _FOUR_DEVICE_FIELD % (MQ, source, source)],
+                       env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stderr[-4000:]
+    assert r.stdout.strip().endswith("ok")

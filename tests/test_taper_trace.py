@@ -208,3 +208,29 @@ def test_spans_land_on_the_profiler_host_plane(tmp_path):
         own = sorted(s["duration_s"] for s in tracer.spans(name=name))
         assert len(ann) == len(own)
         assert all(a <= b for a, b in zip(ann, own))
+
+
+def test_sharded_field_spans_carry_halo_bytes_and_shard_pack(prov):
+    """Under the sharded field (one CPU device, a one-shard mesh): every
+    ``invocation.field`` span carries ``halo_bytes`` = per-depth bytes x
+    depth steps, and ``field.shard_pack`` opens under the first field only
+    (the packing and its device copy do not change after it)."""
+    g, arrays = prov
+    taper, tracer = traced_taper(g, 4, field_backend="pallas_sharded",
+                                 shard_map_source="partition")
+    part = hash_partition(g.n, 4, seed=1)
+    taper.invoke(part, arrays, max_iterations=2)
+    fields = by_name(tracer, "invocation.field")
+    assert len(fields) >= 2
+    for f in fields:
+        a = f["attrs"]
+        assert a["halo_bytes"] == a["halo_bytes_per_depth"] * a["depth_steps"]
+        assert a["depth_steps"] == arrays.max_depth - 1
+    packs = by_name(tracer, "field.shard_pack")
+    assert len(packs) == 1
+    assert packs[0]["parent_id"] == fields[0]["span_id"]
+    assert packs[0]["attrs"] == {"rebuilt": 1, "shards_uploaded": 1}
+    # a field of another partition on the unchanged graph packs nothing
+    taper.field((part + 1) % 4, arrays)
+    assert len(by_name(tracer, "field.shard_pack")) == 1
+    assert len(by_name(tracer, "invocation.field")) == len(fields) + 1

@@ -156,7 +156,8 @@ def test_sharded_field_body_compiles_on_four_chips(topo, pq_trie,
     round_cap = (0, 40_000, 40_000, 40_000)
     fn = _build_sharded_fn(mesh, t, t.max_depth, bps, BLOCK_N, BLOCK_E,
                            n_local_pad, exchange="sliced", n_shards=S,
-                           round_cap=round_cap)
+                           round_cap=round_cap, n=N_V, m=M_E, k=K,
+                           dense_ext_to=False, identity=False)
     sh = NamedSharding(mesh, P("model"))
     rep = NamedSharding(mesh, P())
     i32, f32 = jnp.int32, jnp.float32
@@ -169,9 +170,11 @@ def test_sharded_field_body_compiles_on_four_chips(topo, pq_trie,
         _spec((S, e_pad), i32, sh),              # src_global
         _spec((S, e_pad), i32, sh),              # dst_global
         _spec((S, n_local_pad), i32, sh),        # vlabels
+        _spec((S, e_pad), i32, sh),              # slot_raw
         _spec((S, hot_pad), i32, sh),            # hot_local_idx
         _spec((S, hot_pad), f32, sh),            # hot_owned
         _spec((S, S, max(round_cap)), i32, sh),  # send_local
+        _spec((N_V,), i32, rep),                 # pos (vertex -> position)
         _spec((N_V,), i32, rep),                 # part
         _spec((N,), f32, rep),                   # p
         _spec((L,), i32, rep),                   # lab_vcount
@@ -182,5 +185,7 @@ def test_sharded_field_body_compiles_on_four_chips(topo, pq_trie,
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "collective-permute" in text and "all-reduce" in text
+    # one program for the whole field: loop, reorder, scatter, aggregates
+    assert "sharded_field_fn" in text.splitlines()[0]
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < HBM_BYTES // 4, mem

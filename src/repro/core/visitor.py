@@ -15,9 +15,11 @@ the TPU-native adaptation (DESIGN.md §2):
            extroversion = (sum of mass over cut edges out of v) / Pr(v)
            introversion = 1 - extroversion  (termination mass is intra, §4.2)
 
-Everything is `segment_sum` over edge blocks — the same kernel regime as GNN
-message passing; `repro.kernels.vm_step` provides the Pallas TPU kernel for
-the inner step, and this module is its jnp oracle.
+Everything is a sum over edge blocks — the same kernel regime as GNN
+message passing (the fused program sums each destination's contiguous
+in-edge segment of a destination-sorted edge order);
+`repro.kernels.vm_step` provides the Pallas TPU kernel for the inner step,
+and this module is its jnp oracle.
 
 One jit cache entry exists per (trie topology, graph/partition shapes); trie
 *probabilities* are runtime arguments so workload-frequency drift never
@@ -143,6 +145,54 @@ def _field_aggregates(counted_nodes, k, dense_ext_to,
     return alpha, pr, mass, extro_mass, extroversion
 
 
+def _segment_sums(x, seg, ptr, levels: int):
+    """Sums of the rows of ``x`` over contiguous segments, with no scatter.
+
+    Row ``i`` of ``x`` (``(m, c)``) belongs to segment ``seg[i]``
+    (non-decreasing), and segment ``v`` is rows ``ptr[v]:ptr[v + 1]``
+    (``ptr`` is ``(n + 1,)``).  A Hillis–Steele inclusive scan adds row
+    ``i - 2**j`` into row ``i`` at level ``j`` only when both lie in one
+    segment, so after ``levels`` levels (the bit length of the longest
+    segment less one) a segment's last row holds its total and no sum
+    takes a row of another segment.  Returns ``(n, c)``, zero for an
+    empty segment.
+    """
+    m = x.shape[0]
+    if m == 0:
+        return jnp.zeros((ptr.shape[0] - 1, x.shape[1]), x.dtype)
+    for j in range(levels):
+        s = 1 << j
+        if s >= m:
+            break
+        # row i - s, shifted in by a pad (one fused pass a level)
+        prev = jnp.pad(x, ((s, 0), (0, 0)))[:m]
+        same = jnp.pad(seg, (s, 0), constant_values=-1)[:m] == seg
+        x = x + jnp.where(same[:, None], prev, 0.0)
+    end = jnp.maximum(ptr[1:] - 1, 0)
+    return jnp.where((ptr[1:] > ptr[:-1])[:, None], x[end], 0.0)
+
+
+def _segment_aggregates(counted_nodes, k, dense_ext_to, alpha, mass,
+                        src, dst, part, local, row_ptr, levels, n):
+    """:func:`_field_aggregates` for edges in CSR order: each vertex's
+    out-edges are one segment, so the external mass and ``ext_to`` are
+    :func:`_segment_sums` over ``row_ptr`` in one pass."""
+    pr = jnp.zeros((n,), dtype=jnp.float32)
+    for i in counted_nodes:
+        pr = pr + alpha[:, i]
+    ext = mass * (1.0 - local)
+    cols = [ext[:, None]]
+    if dense_ext_to:
+        to = part[dst][:, None] == jnp.arange(k, dtype=part.dtype)[None, :]
+        cols.append(jnp.where(to, ext[:, None], 0.0))
+    sums = _segment_sums(jnp.concatenate(cols, axis=1), src, row_ptr, levels)
+    extro_mass = sums[:, 0]
+    extroversion = jnp.where(pr > _EPS, extro_mass / jnp.maximum(pr, _EPS), 0.0)
+    if dense_ext_to:
+        return alpha, pr, mass, extro_mass, extroversion, sums[:, 1:]
+    return alpha, pr, mass, extro_mass, extroversion
+
+
 def _build_field_fn(topology: Tuple, trie: TrieArrays, k: int, depth_cap: int,
                     fused: bool = True, dense_ext_to: bool = True):
     """Build the jitted field function for a fixed trie *topology*.
@@ -160,9 +210,15 @@ def _build_field_fn(topology: Tuple, trie: TrieArrays, k: int, depth_cap: int,
     * naive  — one gather + segment_sum pass over the edge list per trie
       node (the direct transcription of the recurrence);
     * fused  — all trie nodes of one depth advance in a single batched
-      gather / elementwise / segment_sum pass (§Perf iteration T1: the
-      naive variant launches ~N_trie scatter passes whose intermediates
-      cannot fuse, and its HBM term is ~5x the fused one).
+      gather / elementwise pass (§Perf iteration T1: the naive variant
+      launches ~N_trie scatter passes whose intermediates cannot fuse, and
+      its HBM term is ~5x the fused one).  It runs over the edges in
+      destination order (``LabelledGraph.in_edge_order``), so each
+      destination's sum is :func:`_segment_sums` over its contiguous
+      in-edge segment rather than a scatter-add by unsorted ``dst`` (on
+      a TPU a scatter costs per update row); the aggregates likewise sum
+      contiguous out-edge segments.  ``levels`` (static) is the bit length
+      of the longest segment less one.
     """
     parent = trie.parent.copy()
     labels_n = trie.label.copy()
@@ -219,35 +275,51 @@ def _build_field_fn(topology: Tuple, trie: TrieArrays, k: int, depth_cap: int,
                 )
         return _aggregates(alpha, mass, src, dst, part, local, n)
 
-    @partial(jax.jit, static_argnames=("n", "m"))
+    @partial(jax.jit, static_argnames=("n", "m", "levels"))
     def field_fn_fused(
-        src, dst, vlabels, cnt, lab_vcount, part, p, cond_p, *, n: int, m: int
+        src, dst, vlabels, cnt, lab_vcount, part, p, cond_p,
+        src_in, dst_in, rank_in, in_ptr, row_ptr, *, n: int, m: int,
+        levels: int,
     ):
-        inv_cnt, local, dst_lab, alpha = _inputs_and_priors(
-            src, dst, vlabels, cnt, lab_vcount, part, p, n)
+        # the edges twice: in CSR order (src, dst) and in destination order
+        # (src_in, dst_in; rank_in maps a CSR edge to its position there),
+        # where every vertex's in-edges are one segment of in_ptr
+        with jax.named_scope("field.priors"):
+            inv_cnt = 1.0 / jnp.maximum(cnt.astype(jnp.float32), 1.0)  # (n, L)
+            local = (part[src] == part[dst]).astype(jnp.float32)       # (m,)
+            local_in = (part[src_in] == part[dst_in]).astype(jnp.float32)
+            lab_in = vlabels[dst_in]
+            # 1 / cnt[u, l(w)] of each edge (u, w)
+            ic_in = inv_cnt.reshape(-1)[src_in * cnt.shape[1] + lab_in]
+            alpha = _prior_columns(depth, labels_n, N, vlabels, lab_vcount,
+                                   p, n)
+        cols = [alpha[:, i] for i in range(N)]
 
-        mass = jnp.zeros((m,), dtype=jnp.float32)
+        mass_in = jnp.zeros((m,), dtype=jnp.float32)
         for d in range(2, max_depth + 1):
             nodes_d = [c for c in step_nodes if depth[c] == d]
             if not nodes_d:
                 continue
-            pars = np.asarray([parent[c] for c in nodes_d])
-            labs = np.asarray([labels_n[c] for c in nodes_d])
+            labs = jnp.asarray(np.asarray([labels_n[c] for c in nodes_d]))
             with jax.named_scope(f"field.depth{d}"):
-                # one batched gather of the needed parent columns: (m, n_d)
-                # (column-slice first so the row gather moves n_d
-                # floats/edge, not the full trie row)
-                a_par = alpha[:, pars][src]
+                # one batched gather of the parent columns at each edge's
+                # source, in destination order: (m, n_d)
+                a_par = jnp.stack([cols[int(parent[c])] for c in nodes_d],
+                                  axis=1)[src_in]
                 coef = cond_p[jnp.asarray(np.asarray(nodes_d))][None, :]
-                lab_mask = (dst_lab[:, None] == jnp.asarray(labs)[None, :])
-                ic = inv_cnt[:, labs][src]
-                contrib = a_par * coef * ic * lab_mask.astype(jnp.float32)
-                mass = mass + contrib.sum(axis=1)
-                # single segment_sum for the whole depth: (n, n_d)
-                upd = jax.ops.segment_sum(contrib * local[:, None], dst,
-                                          num_segments=n)
-                alpha = alpha.at[:, jnp.asarray(np.asarray(nodes_d))].add(upd)
-        return _aggregates(alpha, mass, src, dst, part, local, n)
+                w = jnp.where(lab_in[:, None] == labs[None, :],
+                              ic_in[:, None], 0.0)
+                contrib = a_par * coef * w
+                mass_in = mass_in + contrib.sum(axis=1)
+                # each destination sums its own in-edge segment: (n, n_d)
+                upd = _segment_sums(contrib * local_in[:, None], dst_in,
+                                    in_ptr, levels)
+            for j, c in enumerate(nodes_d):
+                cols[c] = upd[:, j]
+        with jax.named_scope("field.aggregates"):
+            return _segment_aggregates(
+                counted_nodes, k, dense_ext_to, jnp.stack(cols, axis=1),
+                mass_in[rank_in], src, dst, part, local, row_ptr, levels, n)
 
     return field_fn_fused if fused else field_fn_naive
 
@@ -277,6 +349,33 @@ def _device_inputs(g: LabelledGraph, pre: Dict, cnt, lab_vcount) -> Dict:
         pre["_dev"] = dev
         pre["_dev_version"] = g.version
     return dev
+
+
+def _dst_order_inputs(g: LabelledGraph, dev: Dict) -> Dict:
+    """Device copies of the graph's destination order, for the fused jnp
+    program: the edges' sources and destinations in that order, each CSR
+    edge's position in it (``rank``), the in-degree prefix ``in_ptr``, the
+    CSR ``row_ptr`` and the static scan ``levels`` (bit length of the
+    largest in- or out-degree less one).  Kept in the :func:`_device_inputs`
+    dict, so they re-upload with ``src``/``dst`` after a mutation.
+    """
+    order = dev.get("dst_order")
+    if order is None:
+        perm, in_ptr = g.in_edge_order
+        rank = np.empty(g.m, dtype=np.int32)
+        rank[perm] = np.arange(g.m, dtype=np.int32)
+        widest = max(int(np.diff(in_ptr).max(initial=0)),
+                     int(np.diff(g.row_ptr).max(initial=0)))
+        order = {
+            "src": jnp.asarray(g.src[perm]),
+            "dst": jnp.asarray(g.dst[perm]),
+            "rank": jnp.asarray(rank),
+            "in_ptr": jnp.asarray(in_ptr.astype(np.int32)),
+            "row_ptr": jnp.asarray(g.row_ptr.astype(np.int32)),
+            "levels": max(widest - 1, 0).bit_length(),
+        }
+        dev["dst_order"] = order
+    return order
 
 
 _TRANSITION_CACHE: Dict[Tuple, np.ndarray] = {}
@@ -863,19 +962,15 @@ def extroversion_field(
         if lab_vcount is None:
             lab_vcount = g.label_counts()
         dev = _device_inputs(g, pre, cnt, lab_vcount)
-
-        out = fn(
-            dev["src"],
-            dev["dst"],
-            dev["labels"],
-            dev["cnt"],
-            dev["lab_vcount"],
-            jnp.asarray(part.astype(np.int32)),
-            jnp.asarray(trie.p),
-            jnp.asarray(trie.cond_p),
-            n=g.n,
-            m=g.m,
-        )
+        args = (dev["src"], dev["dst"], dev["labels"], dev["cnt"],
+                dev["lab_vcount"], jnp.asarray(part.astype(np.int32)),
+                jnp.asarray(trie.p), jnp.asarray(trie.cond_p))
+        if fused:
+            o = _dst_order_inputs(g, dev)
+            out = fn(*args, o["src"], o["dst"], o["rank"], o["in_ptr"],
+                     o["row_ptr"], n=g.n, m=g.m, levels=o["levels"])
+        else:
+            out = fn(*args, n=g.n, m=g.m)
     else:
         raise ValueError(f"unknown field backend {backend!r}")
     parent = NOOP_SPAN if parent is None else parent

@@ -277,6 +277,8 @@ class LabelledGraph:
     row_ptr: np.ndarray = field(repr=False, default=None)
     version: int = 0
     _rev_index: Optional[np.ndarray] = field(repr=False, default=None, compare=False)
+    _in_order: Optional[Tuple[np.ndarray, np.ndarray]] = field(
+        repr=False, default=None, compare=False)
     _vm_pack_cache: Dict = field(repr=False, default_factory=dict, compare=False)
     _mutation_log: List[AppliedMutation] = field(
         repr=False, default_factory=list, compare=False)
@@ -403,6 +405,24 @@ class LabelledGraph:
             found = (keys[pos] == rkeys) if self.m else np.zeros(0, bool)
             self._rev_index = np.where(found, pos, -1).astype(np.int64)
         return self._rev_index
+
+    @property
+    def in_edge_order(self) -> Tuple[np.ndarray, np.ndarray]:
+        """``(perm, in_ptr)``: the edges in destination order.
+
+        ``perm`` (``(m,)`` int64) is the stable argsort of ``dst`` — within
+        one destination the edges keep their ``(src, dst)`` order — and
+        ``in_ptr`` (``(n+1,)`` int64) the in-degree prefix, so vertex
+        ``w``'s in-edges are ``perm[in_ptr[w]:in_ptr[w + 1]]``, one
+        contiguous segment.  Cached per :attr:`version`
+        (:meth:`apply_mutations` drops it).
+        """
+        if self._in_order is None:
+            perm = np.argsort(self.dst, kind="stable").astype(np.int64)
+            indeg = np.bincount(self.dst, minlength=self.n)
+            in_ptr = np.concatenate([[0], np.cumsum(indeg)]).astype(np.int64)
+            self._in_order = (perm, in_ptr)
+        return self._in_order
 
     def is_symmetric(self) -> bool:
         """True when every directed edge has its reverse present."""
@@ -771,6 +791,7 @@ class LabelledGraph:
         self.dst = dst_new
         self.row_ptr = row_ptr_new
         self._rev_index = rev_new
+        self._in_order = None
         self._vm_pack_cache = patched_entries
         if cnt_new is not None:
             self._vm_pack_cache["_default_cnt"] = cnt_new

@@ -372,9 +372,16 @@ def _taper_cell(cfg: TaperSystemConfig, shape: ShapeSpec, mesh, rules,
     v = _named(mesh, rules, "nodes", shape=(n,))
     rep = NamedSharding(mesh, P())
     in_sh = (e, e, v, _named(mesh, rules, "nodes", None, shape=(n, cfg.n_labels)), rep, v, rep, rep)
+    kw = {"n": n, "m": m}
+    if fused:
+        # the destination order: src, dst and CSR rank of each edge in it,
+        # in-degree and CSR prefixes; no vertex has more than m edges
+        args += (sds((m,), I32),) * 3 + (sds((n + 1,), I32),) * 2
+        in_sh += (e, e, e, rep, rep)
+        kw["levels"] = max(m - 1, 0).bit_length()
 
-    def refine(src, dst, labels, cnt, lab_vcount, part, p, cond_p):
-        return fn(src, dst, labels, cnt, lab_vcount, part, p, cond_p, n=n, m=m)
+    def refine(*a):
+        return fn(*a, **kw)
 
     # outputs: alpha (n,N), pr (n,), mass (m,), extro (n,), extroversion (n,)
     # [, ext_to (n, k)] — all sharded along their vertex/edge dim

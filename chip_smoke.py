@@ -10,8 +10,8 @@ avg_degree=6.0, seed=11)`` (``benchmarks/common.py``), hash-partitioned
 into k = 8, under the PQ1-4 workload with its paper frequencies.  Nothing
 is cut in width: labels, trie, k and balance are the paper's.
 
-* field phase — ``extroversion_field`` with ``backend="jnp"`` and
-  ``backend="pallas"`` against a float64 numpy transcription of the same
+* field phase — ``extroversion_field`` with ``backend="jnp"`` (the
+  one-chip program) against a float64 numpy transcription of the same
   recurrence, on the same graph and partition;
 * serving phase — a ``ServingLoop`` with the default jnp field serves a few
   hundred sampled PQ requests until one overlapped TAPER invocation has
@@ -157,22 +157,21 @@ def timed_field(clock, label, call):
 def field_phase(clock, g, arrays, part) -> None:
     from repro.core.visitor import extroversion_field
 
-    log("field phase: jnp and pallas fields vs float64 numpy reference")
+    log("field phase: jnp field vs float64 numpy reference")
     t0 = time.perf_counter()
     ref = reference_field(g, arrays, part)
     log(f"  reference field on host: {time.perf_counter() - t0:.1f}s, "
         f"total_extroversion={ref[2]:.9g}")
-    for backend in ("jnp", "pallas"):
-        pre = {}
-        fld = timed_field(
-            clock, f"field[{backend}]",
-            lambda: extroversion_field(g, arrays, part, K, _precomputed=pre,
-                                       backend=backend))
-        errs = field_errors(fld, *ref)
-        log(f"  field[{backend}] largest relative error vs reference: "
-            + ", ".join(f"{k}={v:.3e}" for k, v in errs.items()))
-        check(max(errs.values()) <= TOL,
-              f"field[{backend}] within {TOL:g} of the float64 reference")
+    pre = {}
+    fld = timed_field(
+        clock, "field[jnp]",
+        lambda: extroversion_field(g, arrays, part, K, _precomputed=pre,
+                                   backend="jnp"))
+    errs = field_errors(fld, *ref)
+    log("  field[jnp] largest relative error vs reference: "
+        + ", ".join(f"{k}={v:.3e}" for k, v in errs.items()))
+    check(max(errs.values()) <= TOL,
+          f"field[jnp] within {TOL:g} of the float64 reference")
 
 
 def serving_phase(g, part0, workload, seed) -> None:

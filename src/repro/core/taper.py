@@ -25,10 +25,11 @@ log = get_logger("core.taper")
 
 Workload = Sequence[Tuple[RPQ, float]]
 
-#: extroversion-field DP backends ordered by capability: serving loops
-#: degrade left-to-right on repeated device failure (losing scale, keeping
-#: availability) and probe back right-to-left once the fault clears
-FIELD_BACKEND_LADDER = ("pallas_sharded", "pallas", "jnp")
+#: extroversion-field DP backends, one per platform, ordered by capability:
+#: serving loops degrade from the mesh program to the one-device program on
+#: repeated device failure (losing scale, keeping availability) and probe
+#: back up once the fault clears
+FIELD_BACKEND_LADDER = ("pallas_sharded", "jnp")
 
 
 class InvocationAborted(RuntimeError):
@@ -49,7 +50,6 @@ class TaperConfig:
     min_gain: float = 0.0
     safe_introversion: float = 0.95  # §5.2.1 space heuristic
     depth_cap: Optional[int] = None  # §5.2.2 time heuristic (k < t)
-    fused_field: bool = True         # §Perf-T1 batched DP passes
     #: Dense per-destination external-mass matrix (matches the
     #: ``extroversion_field`` default).  True computes the (n, k) ``ext_to``
     #: in the fused device pass — one extra segment_sum, n*k floats — and the
@@ -58,9 +58,9 @@ class TaperConfig:
     #: derives each candidate's preferences lazily from its own cut edges
     #: (cheaper for large k / short candidate queues).
     dense_ext_to: bool = True
-    #: extroversion-field DP engine: "jnp" (fused XLA), "pallas" (vm_step
-    #: kernel, single device) or "pallas_sharded" (vm_step per mesh shard
-    #: with frontier halo exchange — scales the field with device count)
+    #: extroversion-field DP engine: "jnp" (the fused XLA program, one
+    #: device) or "pallas_sharded" (vm_step per mesh shard with frontier
+    #: halo exchange — scales the field with device count)
     field_backend: str = "jnp"
     #: sharded backend only — how vertices are dealt to mesh shards:
     #: "stripe" (contiguous id ranges), "partition" (dealt along the live
@@ -121,6 +121,9 @@ class Taper:
         self.g = g
         self.k = k
         self.config = config or TaperConfig()
+        if self.config.field_backend not in FIELD_BACKEND_LADDER:
+            raise ValueError(
+                f"unknown field backend {self.config.field_backend!r}")
         # partition-independent precomputes shared across invocations; the
         # field functions also cache device-resident edge arrays in here, so
         # only the partition vector is re-uploaded per iteration.  All of it
@@ -290,10 +293,13 @@ class Taper:
         """Switch the extroversion-field DP engine in place.
 
         The serving loop's graceful-degradation ladder calls this to fall
-        from ``pallas_sharded`` toward ``jnp`` on repeated device failure
-        (and to probe back up).  Device-resident caches in ``_pre`` are
-        keyed per backend so they survive the round trip; only the field
-        memo (keyed on the old backend) is dropped."""
+        from ``pallas_sharded`` to ``jnp`` on repeated device failure (and
+        to probe back up).  Each backend keeps its device buffers in its own
+        entries of ``_pre`` (``_dev`` for ``jnp``; ``_shard_dev``, with the
+        sticky ``_shard_order`` and ``_mesh``, for ``pallas_sharded``), and
+        both compiled programs stay in the visitor's program cache, so a
+        switch back re-uses them; each is still checked against the graph's
+        version on its next use.  Only the field memo is dropped."""
         if backend not in FIELD_BACKEND_LADDER:
             raise ValueError(f"unknown field backend {backend!r}")
         if backend == self.config.field_backend:
@@ -328,7 +334,7 @@ class Taper:
             arrays.p.tobytes(),
             arrays.cond_p.tobytes(),
             np.asarray(part, dtype=np.int32).tobytes(),
-            cfg.depth_cap, cfg.fused_field, cfg.dense_ext_to,
+            cfg.depth_cap, cfg.dense_ext_to,
             cfg.field_backend, cfg.halo_exchange,
             self._pre.get("_shard_order", (None,))[0],
             self.k, self.g.version,
@@ -344,7 +350,6 @@ class Taper:
                 self.k,
                 depth_cap=cfg.depth_cap,
                 _precomputed=self._pre,
-                fused=cfg.fused_field,
                 dense_ext_to=cfg.dense_ext_to,
                 backend=cfg.field_backend,
                 shard_map_source=cfg.shard_map_source,

@@ -16,10 +16,11 @@ the TPU-native adaptation (DESIGN.md §2):
            introversion = 1 - extroversion  (termination mass is intra, §4.2)
 
 Everything is a sum over edge blocks — the same kernel regime as GNN
-message passing (the fused program sums each destination's contiguous
-in-edge segment of a destination-sorted edge order);
-`repro.kernels.vm_step` provides the Pallas TPU kernel for the inner step,
-and this module is its jnp oracle.
+message passing.  There is one program per platform: on one device
+(``backend="jnp"``) a fused XLA program sums each destination's contiguous
+in-edge segment of a destination-sorted edge order; on a mesh
+(``backend="pallas_sharded"``, below) each shard advances its destination
+blocks with the ``repro.kernels.vm_step`` Pallas TPU kernel.
 
 One jit cache entry exists per (trie topology, graph/partition shapes); trie
 *probabilities* are runtime arguments so workload-frequency drift never
@@ -116,8 +117,8 @@ _FIELD_CACHE: Dict[Tuple, object] = {}
 def _prior_columns(depth, labels_n, N, vlabels, lab_vcount, p, n):
     """Depth-1 prior columns ``alpha[v, n1] = p(n1) / |{u : l(u)=label(n1)}|``.
 
-    Shared by the jnp and Pallas backends so the base case is arithmetically
-    identical (float32 division on-device) in both."""
+    Shared by the jnp and sharded backends so the base case is
+    arithmetically identical (float32 division on-device) in both."""
     cols = []
     for i in range(N):
         if depth[i] == 1:
@@ -131,7 +132,8 @@ def _prior_columns(depth, labels_n, N, vlabels, lab_vcount, p, n):
 
 def _field_aggregates(counted_nodes, k, dense_ext_to,
                       alpha, mass, src, dst, part, local, n):
-    """Pr / extroversion / (optional) ext_to tail, shared by both backends."""
+    """Pr / extroversion / (optional) ext_to tail of the sharded program,
+    over its edge slots in any order."""
     pr = jnp.zeros((n,), dtype=jnp.float32)
     for i in counted_nodes:
         pr = pr + alpha[:, i]
@@ -194,7 +196,7 @@ def _segment_aggregates(counted_nodes, k, dense_ext_to, alpha, mass,
 
 
 def _build_field_fn(topology: Tuple, trie: TrieArrays, k: int, depth_cap: int,
-                    fused: bool = True, dense_ext_to: bool = True):
+                    dense_ext_to: bool = True):
     """Build the jitted field function for a fixed trie *topology*.
 
     Topology (parent/label/leaf structure) is baked in as Python-level loop
@@ -205,20 +207,15 @@ def _build_field_fn(topology: Tuple, trie: TrieArrays, k: int, depth_cap: int,
     (per-edge inputs and the depth-1 priors), ``field.depth<d>`` (one DP
     depth step) and ``field.aggregates`` (Pr, extroversion, ``ext_to``).
 
-    Two implementations (numerically identical; tested against each other):
-
-    * naive  — one gather + segment_sum pass over the edge list per trie
-      node (the direct transcription of the recurrence);
-    * fused  — all trie nodes of one depth advance in a single batched
-      gather / elementwise pass (§Perf iteration T1: the naive variant
-      launches ~N_trie scatter passes whose intermediates cannot fuse, and
-      its HBM term is ~5x the fused one).  It runs over the edges in
-      destination order (``LabelledGraph.in_edge_order``), so each
-      destination's sum is :func:`_segment_sums` over its contiguous
-      in-edge segment rather than a scatter-add by unsorted ``dst`` (on
-      a TPU a scatter costs per update row); the aggregates likewise sum
-      contiguous out-edge segments.  ``levels`` (static) is the bit length
-      of the longest segment less one.
+    All trie nodes of one depth advance in a single batched gather /
+    elementwise pass (§Perf iteration T1: one pass per trie node launches
+    ~N_trie scatter passes whose intermediates cannot fuse).  The program
+    runs over the edges in destination order
+    (``LabelledGraph.in_edge_order``), so each destination's sum is
+    :func:`_segment_sums` over its contiguous in-edge segment rather than a
+    scatter-add by unsorted ``dst`` (on a TPU a scatter costs per update
+    row); the aggregates likewise sum contiguous out-edge segments.
+    ``levels`` (static) is the bit length of the longest segment less one.
     """
     parent = trie.parent.copy()
     labels_n = trie.label.copy()
@@ -235,45 +232,6 @@ def _build_field_fn(topology: Tuple, trie: TrieArrays, k: int, depth_cap: int,
         i for i in range(N)
         if 1 <= depth[i] < max_depth and not is_leaf[i]
     ]
-
-    def _inputs_and_priors(src, dst, vlabels, cnt, lab_vcount, part, p, n):
-        with jax.named_scope("field.priors"):
-            inv_cnt = 1.0 / jnp.maximum(cnt.astype(jnp.float32), 1.0)  # (n, L)
-            local = (part[src] == part[dst]).astype(jnp.float32)       # (m,)
-            dst_lab = vlabels[dst]                                     # (m,)
-            alpha = _prior_columns(depth, labels_n, N, vlabels, lab_vcount,
-                                   p, n)
-        return inv_cnt, local, dst_lab, alpha
-
-    def _aggregates(alpha, mass, src, dst, part, local, n):
-        with jax.named_scope("field.aggregates"):
-            return _field_aggregates(counted_nodes, k, dense_ext_to,
-                                     alpha, mass, src, dst, part, local, n)
-
-    @partial(jax.jit, static_argnames=("n", "m"))
-    def field_fn_naive(
-        src, dst, vlabels, cnt, lab_vcount, part, p, cond_p, *, n: int, m: int
-    ):
-        inv_cnt, local, dst_lab, alpha = _inputs_and_priors(
-            src, dst, vlabels, cnt, lab_vcount, part, p, n)
-
-        # --- DP steps + edge masses, one pass per depth>=2 node ---
-        mass = jnp.zeros((m,), dtype=jnp.float32)
-        for c in step_nodes:
-            par, lc = int(parent[c]), int(labels_n[c])
-            with jax.named_scope(f"field.depth{int(depth[c])}"):
-                contrib = (
-                    alpha[src, par]
-                    * cond_p[c]
-                    * inv_cnt[src, lc]
-                    * (dst_lab == lc).astype(jnp.float32)
-                )
-                mass = mass + contrib
-                # only local (intra-partition) extensions continue the path
-                alpha = alpha.at[:, c].add(
-                    jax.ops.segment_sum(contrib * local, dst, num_segments=n)
-                )
-        return _aggregates(alpha, mass, src, dst, part, local, n)
 
     @partial(jax.jit, static_argnames=("n", "m", "levels"))
     def field_fn_fused(
@@ -321,7 +279,7 @@ def _build_field_fn(topology: Tuple, trie: TrieArrays, k: int, depth_cap: int,
                 counted_nodes, k, dense_ext_to, jnp.stack(cols, axis=1),
                 mass_in[rank_in], src, dst, part, local, row_ptr, levels, n)
 
-    return field_fn_fused if fused else field_fn_naive
+    return field_fn_fused
 
 
 def _device_inputs(g: LabelledGraph, pre: Dict, cnt, lab_vcount) -> Dict:
@@ -399,118 +357,6 @@ def _capped_transition(trie: TrieArrays, depth_cap: int) -> np.ndarray:
             _TRANSITION_CACHE.pop(next(iter(_TRANSITION_CACHE)))
         _TRANSITION_CACHE[key] = T
     return T
-
-
-@partial(jax.jit, static_argnames=("n", "n_blocks_out", "block_n", "block_e"))
-def _pallas_depth_step(beta_t, T, Tsum, src, dst_lab, inv_cnt_edge,
-                       packed_src, dst_local, dst_label, inv_local, meta_t,
-                       *, n: int, n_blocks_out: int, block_n: int,
-                       block_e: int):
-    """One depth of the ``pallas`` field, feature-major (``beta_t`` is
-    ``(N, n)``): the step's per-edge mass over ALL edges, and the next delta
-    state over local edges through the ``vm_step`` kernel."""
-    from repro.kernels.vm_step.kernel import vm_step_packed
-
-    mass = (jnp.take(beta_t, src, axis=1)
-            * jnp.take(Tsum.T, dst_lab, axis=1)).sum(axis=0) * inv_cnt_edge
-    out_t = vm_step_packed(
-        jnp.take(beta_t, packed_src, axis=1), T, dst_local, dst_label,
-        inv_local, meta_t, n_blocks_out, block_n, block_e)
-    return out_t[:, :n], mass
-
-
-def _pallas_field(
-    g: LabelledGraph,
-    trie: TrieArrays,
-    part: np.ndarray,
-    k: int,
-    depth_cap: int,
-    pre: Dict,
-    dense_ext_to: bool,
-):
-    """Pallas-backed extroversion field: the depth-advancing DP step runs as
-    the ``vm_step`` TPU kernel over the graph's cached edge packing.
-
-    The depth recurrence is expressed as a chain of *delta* states: ``beta_d``
-    holds only the depth-``d`` trie columns, so applying the full transition
-    tensor once per depth advances every state without double counting:
-
-        beta_1 = priors;  beta_d = vm_step(beta_{d-1}, T | local edges)
-        alpha  = sum_d beta_d
-        mass  += rowsum over children of the beta_{d-1} messages (ALL edges)
-
-    The states are kept feature-major (``(N, n)``), the kernel's layout.
-    The packing (src/dst/label/1-cnt channels) is partition-independent and
-    cached on the graph; per iteration only the partition vector and the
-    derived local-edge mask move to the device.
-    """
-    n, m = g.n, g.m
-    N = trie.n_nodes
-    cnt = pre.get("cnt")
-    if cnt is None:
-        cnt = g.neighbor_label_counts()
-    lab_vcount = pre.get("lab_vcount")
-    if lab_vcount is None:
-        lab_vcount = g.label_counts()
-    dev = _device_inputs(g, pre, cnt, lab_vcount)
-    src, dst, vlabels = dev["src"], dev["dst"], dev["labels"]
-
-    packed, dst_label, inv_cnt_packed, dst_global = g.vm_packing(cnt=cnt)
-    pdev = pre.get("_vm_dev")
-    if pdev is not None and pre.get("_vm_dev_version") != g.version:
-        pdev = None  # stale device packing from a pre-mutation graph
-    if pdev is None:
-        inv_cnt_edge = 1.0 / np.maximum(
-            np.asarray(cnt)[g.src, g.labels[g.dst]], 1.0)
-        pdev = {
-            "packed_src": jnp.asarray(packed.src),
-            "dst_local": jnp.asarray(packed.dst_local),
-            "meta_t": jnp.asarray(packed.meta.T),
-            "dst_global": jnp.asarray(dst_global),
-            "inv_cnt_edge": jnp.asarray(inv_cnt_edge.astype(np.float32)),
-        }
-        pre["_vm_dev"] = pdev
-        pre["_vm_dev_version"] = g.version
-
-    # device-resident transition tensor, re-uploaded only when the trie
-    # probabilities (or depth cap) change — not per iteration
-    T_key = (trie.topology_signature(), int(depth_cap), trie.cond_p.tobytes())
-    t_hit = pre.get("_T_dev")
-    if t_hit is None or t_hit[0] != T_key:
-        T = jnp.asarray(_capped_transition(trie, depth_cap))
-        Tsum = T.sum(axis=2)                   # (L, N) mass per (label, parent)
-        pre["_T_dev"] = (T_key, T, Tsum)
-    else:
-        _, T, Tsum = t_hit
-    part_dev = jnp.asarray(part.astype(np.int32))
-    local = (part_dev[src] == part_dev[dst]).astype(jnp.float32)   # (m,)
-    local_packed = (part_dev[pdev["packed_src"]]
-                    == part_dev[pdev["dst_global"]]).astype(jnp.float32)
-    inv_local = inv_cnt_packed * local_packed  # 0 on padding (inv_cnt is 0)
-    dst_lab = vlabels[dst]
-
-    # depth-1 priors — same device arithmetic as the jnp backend
-    alpha_t = _prior_columns(trie.depth, trie.label, N, vlabels,
-                             dev["lab_vcount"], jnp.asarray(trie.p), n).T
-    beta_t = alpha_t
-    mass = jnp.zeros((m,), dtype=jnp.float32)
-    max_depth = min(trie.max_depth, depth_cap)
-    for _ in range(2, max_depth + 1):
-        beta_t, step_mass = _pallas_depth_step(
-            beta_t, T, Tsum, src, dst_lab, pdev["inv_cnt_edge"],
-            pdev["packed_src"], pdev["dst_local"], dst_label, inv_local,
-            pdev["meta_t"], n=n, n_blocks_out=packed.n_blocks_out,
-            block_n=packed.block_n, block_e=packed.block_e)
-        mass = mass + step_mass
-        alpha_t = alpha_t + beta_t
-    alpha = alpha_t.T
-
-    counted = [
-        i for i in range(N)
-        if 1 <= int(trie.depth[i]) < max_depth and not bool(trie.is_leaf[i])
-    ]
-    return _field_aggregates(counted, k, dense_ext_to,
-                             alpha, mass, src, dst, part_dev, local, n)
 
 
 def _build_sharded_fn(mesh, trie: TrieArrays, depth_cap: int,
@@ -897,7 +743,6 @@ def extroversion_field(
     k: int,
     depth_cap: Optional[int] = None,
     _precomputed: Optional[Dict] = None,
-    fused: bool = True,
     dense_ext_to: bool = True,
     backend: str = "jnp",
     shard_map_source: str = "stripe",
@@ -918,13 +763,13 @@ def extroversion_field(
     cheaper when ``k`` is large or candidate queues are short, at the cost
     of a little host work per candidate.
 
-    ``backend`` selects the DP engine: ``"jnp"`` (the fused XLA
-    transcription), ``"pallas"`` (the ``vm_step`` TPU kernel over the
-    graph's cached edge packing; interpret mode auto-disables on TPU) or
-    ``"pallas_sharded"`` (the same kernel per shard over every visible
-    device, halo-exchanging only the cross-shard ``beta`` rows between
-    depth steps — see the module docstring; seed ``_precomputed["_mesh"]``
-    to pin a specific mesh).  ``shard_map_source`` / ``halo_exchange``
+    ``backend`` selects the DP engine, one per platform: ``"jnp"`` (the
+    fused XLA program, on one device) or ``"pallas_sharded"`` (the
+    ``vm_step`` TPU kernel per shard over every visible device,
+    halo-exchanging only the cross-shard ``beta`` rows between depth
+    steps — see the module docstring; interpret mode auto-disables on TPU;
+    seed ``_precomputed["_mesh"]`` to pin a specific mesh).  Any other
+    name raises ``ValueError``.  ``shard_map_source`` / ``halo_exchange``
     apply to the sharded backend only: how vertices are dealt to shards
     (``"stripe"`` | ``"partition"`` | ``"bfs"``) and whether the exchange
     moves per-shard-pair slices (``"sliced"``: a psum'd hot union plus
@@ -937,9 +782,7 @@ def extroversion_field(
     """
     depth_cap = depth_cap or trie.max_depth
     pre = _precomputed if _precomputed is not None else {}
-    if backend == "pallas":
-        out = _pallas_field(g, trie, part, k, depth_cap, pre, dense_ext_to)
-    elif backend == "pallas_sharded":
+    if backend == "pallas_sharded":
         out = _pallas_sharded_field(g, trie, part, k, depth_cap, pre,
                                     dense_ext_to,
                                     shard_map_source=shard_map_source,
@@ -947,11 +790,11 @@ def extroversion_field(
                                     parent=NOOP_SPAN if parent is None
                                     else parent)
     elif backend == "jnp":
-        key = (trie.topology_signature(), k, depth_cap, g.n, g.m, fused,
+        key = (trie.topology_signature(), k, depth_cap, g.n, g.m,
                dense_ext_to)
         fn = _FIELD_CACHE.get(key)
         if fn is None:
-            fn = _build_field_fn(key, trie, k, depth_cap, fused=fused,
+            fn = _build_field_fn(key, trie, k, depth_cap,
                                  dense_ext_to=dense_ext_to)
             _FIELD_CACHE[key] = fn
 
@@ -962,15 +805,12 @@ def extroversion_field(
         if lab_vcount is None:
             lab_vcount = g.label_counts()
         dev = _device_inputs(g, pre, cnt, lab_vcount)
-        args = (dev["src"], dev["dst"], dev["labels"], dev["cnt"],
-                dev["lab_vcount"], jnp.asarray(part.astype(np.int32)),
-                jnp.asarray(trie.p), jnp.asarray(trie.cond_p))
-        if fused:
-            o = _dst_order_inputs(g, dev)
-            out = fn(*args, o["src"], o["dst"], o["rank"], o["in_ptr"],
-                     o["row_ptr"], n=g.n, m=g.m, levels=o["levels"])
-        else:
-            out = fn(*args, n=g.n, m=g.m)
+        o = _dst_order_inputs(g, dev)
+        out = fn(dev["src"], dev["dst"], dev["labels"], dev["cnt"],
+                 dev["lab_vcount"], jnp.asarray(part.astype(np.int32)),
+                 jnp.asarray(trie.p), jnp.asarray(trie.cond_p),
+                 o["src"], o["dst"], o["rank"], o["in_ptr"], o["row_ptr"],
+                 n=g.n, m=g.m, levels=o["levels"])
     else:
         raise ValueError(f"unknown field backend {backend!r}")
     parent = NOOP_SPAN if parent is None else parent

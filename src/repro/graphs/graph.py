@@ -10,7 +10,7 @@ Dynamic graphs (online TAPER): :meth:`LabelledGraph.apply_mutations` applies
 a batched :class:`MutationBatch` of edge/vertex insertions and deletions
 *in place*, incrementally patching the sorted edge arrays, ``row_ptr``, the
 cached ``reverse_edge_index``, the cached neighbour-label count matrix and
-any cached ``vm_packing`` entries (merge-patch, not rebuild).  Every
+any cached sharded edge packings (per dirty shard, not rebuild).  Every
 successful batch bumps :attr:`LabelledGraph.version`; consumers holding
 graph-derived state (device-resident buffers in ``repro.core.visitor``, the
 executor's per-query traversal-count cache, ...) compare their recorded
@@ -428,46 +428,6 @@ class LabelledGraph:
         """True when every directed edge has its reverse present."""
         return bool((self.reverse_edge_index >= 0).all()) if self.m else True
 
-    def vm_packing(self, cnt: Optional[np.ndarray] = None,
-                   block_n: int = 128, block_e: int = 256):
-        """Cached edge packing for the ``vm_step`` Pallas kernel.
-
-        Returns ``(packed, dst_label, inv_cnt, dst_global)`` where the first
-        three follow :func:`repro.kernels.vm_step.ops.pack_vm_inputs` and
-        ``dst_global`` is the ``(E_pad,)`` global destination id per packed
-        slot.  Padding slots alias the first vertex of their block
-        (``dst_local == 0``, i.e. ``block_id * block_n``) — use
-        ``packed.pad_mask``, not ``dst_global``, to identify padding; the
-        zeroed ``inv_cnt`` channel is what neutralises padded slots in the
-        kernel.  The packing depends only on the graph (not on
-        any partitioning), so it is computed once and reused across every
-        extroversion-field evaluation/iteration; :meth:`apply_mutations`
-        merge-patches cached entries block-by-block instead of re-packing.
-        A non-default ``cnt`` is checked against the cached one — a mismatch
-        rebuilds rather than silently returning channels derived from a
-        different count matrix.
-        """
-        # normalise first so a cnt=None call never aliases an entry built
-        # from a custom count matrix (the graph's own counts are cached too)
-        if cnt is None:
-            cnt = self.cached_neighbor_label_counts()
-        key = (int(block_n), int(block_e))
-        hit = self._vm_pack_cache.get(key)
-        if hit is not None:
-            cached_cnt, entry = hit
-            if cached_cnt is cnt or np.array_equal(cnt, cached_cnt):
-                return entry
-        from repro.kernels.vm_step.ops import pack_vm_inputs
-
-        packed, dst_label, inv_cnt = pack_vm_inputs(
-            self.src, self.dst, self.labels, cnt, self.n,
-            block_n=block_n, block_e=block_e)
-        dst_global = (np.repeat(packed.meta[:, 0], packed.block_e)
-                      * packed.block_n) + packed.dst_local
-        entry = (packed, dst_label, inv_cnt, dst_global.astype(np.int32))
-        self._vm_pack_cache[key] = (np.asarray(cnt), entry)
-        return entry
-
     def vm_packing_sharded(self, n_shards: int,
                            cnt: Optional[np.ndarray] = None,
                            block_n: int = 128, block_e: int = 256,
@@ -476,13 +436,15 @@ class LabelledGraph:
         """Cached shard-aware edge packing for the multi-device field.
 
         Returns a :class:`repro.graphs.sharded_packing.ShardedVMPacking`:
-        the ``vm_packing`` destination blocks dealt across ``n_shards``
-        shards along the ``order`` shard map (a vertex -> position
-        permutation; ``None`` = contiguous id stripes), with per-shard
-        local/halo source index maps and both halo-exchange table sets (see
-        that module's docstring).  Cached per ``(n_shards, block_n,
-        block_e)`` and version-keyed like :meth:`vm_packing`; a call with a
-        different ``order_token`` re-deals (rebuilds) the cached entry.
+        the ``vm_step`` kernel's destination blocks (``block_n`` vertices,
+        edge blocks of ``block_e`` slots) dealt across ``n_shards`` shards
+        along the ``order`` shard map (a vertex -> position permutation;
+        ``None`` = contiguous id stripes), with per-shard local/halo source
+        index maps and both halo-exchange table sets (see that module's
+        docstring).  The packing depends only on the graph, not on any
+        partitioning.  Cached per ``(n_shards, block_n, block_e)`` and keyed
+        by :attr:`version` and ``cnt``; a call with a different
+        ``order_token`` re-deals (rebuilds) the cached entry.
         :meth:`apply_mutations` patches cached entries per dirty shard
         (bumping their ``shard_epoch`` counters so device caches re-upload
         only changed shard slices), evicting only when the mutation
@@ -537,7 +499,7 @@ class LabelledGraph:
         mask, additions are merged by one ``searchsorted`` pass — no
         re-sort.  ``row_ptr`` is rebuilt from patched degree counts (O(n)),
         and the cached ``reverse_edge_index``, neighbour-label counts and
-        ``vm_packing`` entries are patched rather than recomputed.  Bumps
+        sharded edge packings are patched rather than recomputed.  Bumps
         :attr:`version` and appends to :attr:`mutation_log` unless the batch
         turns out to be a no-op.
         """
@@ -751,7 +713,7 @@ class LabelledGraph:
                 np.subtract.at(cnt_new, (rl_in_src, rl_in_old), 1)
                 np.add.at(cnt_new, (rl_in_src, rl_in_new), 1)
 
-        # ---- patch cached vm_packing entries (block merge-patch) ---------
+        # ---- what changed, for the cached sharded packings ---------------
         changed_dsts = np.unique(np.concatenate(
             [removed_dst.astype(np.int64), add_d, rl_v]))
         changed_pairs = np.unique(np.concatenate([
@@ -761,28 +723,8 @@ class LabelledGraph:
             rl_in_src * L + rl_in_old,
             rl_in_src * L + rl_in_new,
         ]))
-        patched_entries = {}
-        sharded_items = []
-        for key, hit in self._vm_pack_cache.items():
-            if key == "_default_cnt":
-                continue
-            if isinstance(key, tuple) and key and key[0] == "sharded":
-                sharded_items.append((key, hit))
-                continue
-            cached_cnt, entry = hit
-            patchable = (
-                cnt_new is not None
-                and rev_new is not None
-                and (rev_new >= 0 if m_new else np.ones(0, bool)).all()
-                and (cached_cnt is cnt_old
-                     or np.array_equal(cached_cnt, cnt_old))
-            )
-            if patchable:
-                patched_entries[key] = (cnt_new, self._patch_vm_entry(
-                    key, entry, src_new, dst_new, row_ptr_new, labels_final,
-                    cnt_new, rev_new, n_new, changed_dsts, changed_pairs))
-            # non-patchable entries (custom cnt, asymmetric graph) are
-            # evicted and rebuilt lazily on next use
+        sharded_items = [(key, hit) for key, hit in self._vm_pack_cache.items()
+                         if key != "_default_cnt"]
 
         # ---- commit ------------------------------------------------------
         self.n = n_new
@@ -792,7 +734,7 @@ class LabelledGraph:
         self.row_ptr = row_ptr_new
         self._rev_index = rev_new
         self._in_order = None
-        self._vm_pack_cache = patched_entries
+        self._vm_pack_cache = {}
         if cnt_new is not None:
             self._vm_pack_cache["_default_cnt"] = cnt_new
         self.version += 1
@@ -844,111 +786,6 @@ class LabelledGraph:
                 compose_mutations(self._mutation_log[0],
                                   self._mutation_log[1])]
         return applied
-
-    def _patch_vm_entry(self, key, entry, src_new, dst_new, row_ptr_new,
-                        labels_new, cnt_new, rev_new, n_new,
-                        changed_dsts, changed_pairs):
-        """Merge-patch one cached ``vm_packing`` entry.
-
-        Exploits symmetry: the dst-sorted edge view that ``pack_edges``
-        builds is exactly the swapped raw arrays (the j-th ``(dst, src)``
-        pair in sorted order is the j-th raw ``(src, dst)`` pair with roles
-        exchanged), and its sort permutation is the reverse-edge involution.
-        Only dst-blocks containing a mutated endpoint are re-packed; the
-        rest are copied slice-wise, with ``inv_cnt`` refreshed for slots
-        whose ``(src, dst-label)`` count changed.
-        """
-        import jax.numpy as jnp
-
-        bn, be = key
-        packed_old, dst_label_old, inv_cnt_old, _ = entry
-        nb_old = packed_old.n_blocks_out
-        nb_new = (n_new + bn - 1) // bn
-
-        aff = np.unique(np.concatenate([
-            changed_dsts // bn, np.arange(nb_old, nb_new, dtype=np.int64)]))
-        aff = aff[aff < nb_new]
-        aff_mask = np.zeros(nb_new, dtype=bool)
-        aff_mask[aff] = True
-
-        old_eb = np.bincount(packed_old.meta[:, 0], minlength=nb_old)
-        new_eb = np.zeros(nb_new, dtype=np.int64)
-        new_eb[:min(nb_old, nb_new)] = old_eb[:min(nb_old, nb_new)]
-        # per-block real edge counts from the new CSR (in-deg == out-deg)
-        v_hi = np.minimum((aff + 1) * bn, n_new)
-        blk_cnt = row_ptr_new[v_hi] - row_ptr_new[np.minimum(aff * bn, n_new)]
-        new_eb[aff] = np.maximum(1, -(-blk_cnt // be))
-        old_off = np.concatenate([[0], np.cumsum(old_eb)]) * be
-        new_off = np.concatenate([[0], np.cumsum(new_eb)]) * be
-        e_pad = int(new_off[-1])
-
-        src_p = np.zeros(e_pad, dtype=np.int32)
-        dloc_p = np.zeros(e_pad, dtype=np.int32)
-        mask_p = np.zeros(e_pad, dtype=bool)
-        dlab_p = np.zeros(e_pad, dtype=np.int32)
-        inv_p = np.zeros(e_pad, dtype=np.float32)
-
-        o_src = np.asarray(packed_old.src)
-        o_dloc = np.asarray(packed_old.dst_local)
-        o_mask = np.asarray(packed_old.pad_mask)
-        o_dlab = np.asarray(dst_label_old)
-        o_inv = np.asarray(inv_cnt_old)
-
-        # copy runs of unaffected blocks wholesale
-        b = 0
-        while b < min(nb_old, nb_new):
-            if aff_mask[b]:
-                b += 1
-                continue
-            e = b
-            while e < min(nb_old, nb_new) and not aff_mask[e]:
-                e += 1
-            slo, shi = int(old_off[b]), int(old_off[e])
-            dlo = int(new_off[b])
-            span = shi - slo
-            src_p[dlo:dlo + span] = o_src[slo:shi]
-            dloc_p[dlo:dlo + span] = o_dloc[slo:shi]
-            mask_p[dlo:dlo + span] = o_mask[slo:shi]
-            dlab_p[dlo:dlo + span] = o_dlab[slo:shi]
-            inv_p[dlo:dlo + span] = o_inv[slo:shi]
-            b = e
-
-        # rebuild affected blocks from the swapped raw arrays
-        for blk in aff.tolist():
-            vlo, vhi_b = blk * bn, min((blk + 1) * bn, n_new)
-            lo, hi = int(row_ptr_new[vlo]), int(row_ptr_new[vhi_b])
-            c = hi - lo
-            o = int(new_off[blk])
-            if c:
-                src_p[o:o + c] = dst_new[lo:hi]
-                dloc_p[o:o + c] = src_new[lo:hi] - vlo
-                mask_p[o:o + c] = True
-                dlab_p[o:o + c] = labels_new[src_new[lo:hi]]
-                inv_p[o:o + c] = 1.0 / np.maximum(
-                    cnt_new[dst_new[lo:hi], labels_new[src_new[lo:hi]]], 1.0)
-
-        # refresh inv_cnt where the (src, dst-label) count changed
-        if changed_pairs.size:
-            slot_keys = src_p.astype(np.int64) * self.n_labels + dlab_p
-            upd = mask_p & np.isin(slot_keys, changed_pairs)
-            if upd.any():
-                inv_p[upd] = 1.0 / np.maximum(
-                    cnt_new[src_p[upd], dlab_p[upd]], 1.0)
-
-        meta = np.zeros((int(new_eb.sum()), 2), dtype=np.int32)
-        meta[:, 0] = np.repeat(
-            np.arange(nb_new, dtype=np.int64), new_eb).astype(np.int32)
-        firsts = np.concatenate([[0], np.cumsum(new_eb)[:-1]])
-        meta[firsts, 1] = 1
-
-        from repro.kernels.segment_spmm.ops import PackedEdges
-
-        packed_new = PackedEdges(
-            src=src_p, dst_local=dloc_p, meta=meta, pad_mask=mask_p,
-            order=rev_new, n_blocks_out=int(nb_new), block_n=bn, block_e=be)
-        dst_global = (np.repeat(meta[:, 0], be) * bn + dloc_p).astype(np.int32)
-        return (packed_new, jnp.asarray(dlab_p), jnp.asarray(inv_p),
-                dst_global)
 
     def subgraph_mask(self, vmask: np.ndarray) -> "LabelledGraph":
         """Induced subgraph on the vertices where ``vmask`` is True.

@@ -1,8 +1,8 @@
 """Shard-aware edge packing for the multi-device extroversion field.
 
-Partitions the per-graph ``vm_packing`` destination blocks across a device
-mesh's ``model`` axis so the ``vm_step`` Pallas kernel can run one shard per
-device over its *local* edge blocks.
+Packs the graph's edges into the ``vm_step`` Pallas kernel's destination
+blocks and deals them across a device mesh's ``model`` axis, so the kernel
+can run one shard per device over its *local* edge blocks.
 
 **Index spaces.**  The packing separates a vertex's *id* from its *position*
 in the shard layout: a pluggable **shard map** (a vertex permutation
@@ -69,8 +69,8 @@ to the compute layout.
   back into the graph's raw edge order on the device, inside the field's
   one jitted program.
 
-Like :meth:`LabelledGraph.vm_packing`, the packing is partition-independent
-*given a shard map* and version-keyed.  After
+The packing is partition-independent *given a shard map* and
+version-keyed.  After
 :meth:`LabelledGraph.apply_mutations` the cached packing is **patched per
 dirty shard** (:func:`patch_sharded_vm_packing`): only shards whose
 destination blocks contain a mutated endpoint are refilled, new halo
@@ -372,8 +372,7 @@ def _dst_sorted_view(
 
     Under the identity shard map, symmetric graphs get this for free: the
     dst-sorted view is the raw arrays with roles swapped, and the sort
-    permutation is the reverse-edge involution (the identity ``vm_packing``
-    patching already exploits)."""
+    permutation is the reverse-edge involution."""
     if sp is not None:
         pos_of, identity = sp.pos_of, sp.identity
     if identity:

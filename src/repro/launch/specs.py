@@ -351,13 +351,13 @@ def _dlrm_cell(cfg: DLRMConfig, shape: ShapeSpec, mesh, rules,
 
 
 def _taper_cell(cfg: TaperSystemConfig, shape: ShapeSpec, mesh, rules,
-                fused: bool = True, dense_ext_to: bool = False) -> CellPlan:
+                dense_ext_to: bool = False) -> CellPlan:
     n = shape.dim("n_vertices")
     m = shape.dim("n_edges")
     trie = synthetic_trie(cfg.n_labels, cfg.trie_depth, branching=2)
     k = cfg.k_partitions
-    key = (trie.topology_signature(), k, trie.max_depth, n, m, fused, dense_ext_to)
-    fn = _build_field_fn(key, trie, k, trie.max_depth, fused=fused,
+    key = (trie.topology_signature(), k, trie.max_depth, n, m, dense_ext_to)
+    fn = _build_field_fn(key, trie, k, trie.max_depth,
                          dense_ext_to=dense_ext_to)
 
     args = (
@@ -367,18 +367,18 @@ def _taper_cell(cfg: TaperSystemConfig, shape: ShapeSpec, mesh, rules,
         sds((cfg.n_labels,), I32),                       # label vertex counts
         sds((n,), I32),                                  # part
         sds((trie.n_nodes,), F32), sds((trie.n_nodes,), F32),  # p, cond_p
+        # the destination order: src, dst and CSR rank of each edge in it,
+        # in-degree and CSR prefixes
+        sds((m,), I32), sds((m,), I32), sds((m,), I32),
+        sds((n + 1,), I32), sds((n + 1,), I32),
     )
     e = _named(mesh, rules, "edges", shape=(m,))
     v = _named(mesh, rules, "nodes", shape=(n,))
     rep = NamedSharding(mesh, P())
-    in_sh = (e, e, v, _named(mesh, rules, "nodes", None, shape=(n, cfg.n_labels)), rep, v, rep, rep)
-    kw = {"n": n, "m": m}
-    if fused:
-        # the destination order: src, dst and CSR rank of each edge in it,
-        # in-degree and CSR prefixes; no vertex has more than m edges
-        args += (sds((m,), I32),) * 3 + (sds((n + 1,), I32),) * 2
-        in_sh += (e, e, e, rep, rep)
-        kw["levels"] = max(m - 1, 0).bit_length()
+    in_sh = (e, e, v, _named(mesh, rules, "nodes", None, shape=(n, cfg.n_labels)), rep, v, rep, rep,
+             e, e, e, rep, rep)
+    # no vertex has more than m edges
+    kw = {"n": n, "m": m, "levels": max(m - 1, 0).bit_length()}
 
     def refine(*a):
         return fn(*a, **kw)
@@ -424,7 +424,7 @@ def build_cell(arch: str, shape_name: str, mesh: Mesh,
         plan = _dlrm_cell(cfg, shape, mesh, rules, **pick("optimizer"))
     elif cfg.family == "taper":
         plan = _taper_cell(cfg, shape, mesh, rules,
-                           **pick("fused", "dense_ext_to"))
+                           **pick("dense_ext_to"))
     else:
         raise ValueError(cfg.family)
     plan.mesh = mesh

@@ -58,7 +58,7 @@ lifecycle diagram):
   failures in its window at the configured error rate, or that many
   consecutive failures (the historic strike count as the degenerate
   case) — walks ``field_backend`` one rung down ``FIELD_BACKEND_LADDER``
-  (``pallas_sharded → pallas → jnp``: lose scale, keep availability);
+  (``pallas_sharded → jnp``: lose scale, keep availability);
   after ``backend_probe_after`` healthy commits the loop probes one rung
   back up, doubling the dwell after each failed probe so a flapping
   device converges to its stable rung;

@@ -25,16 +25,8 @@ def _assert_full_parity(g: LabelledGraph, queries=()):
     assert np.array_equal(g.reverse_edge_index, g2.reverse_edge_index)
     assert np.array_equal(
         g.cached_neighbor_label_counts(), g2.neighbor_label_counts())
-    p1, dl1, ic1, dg1 = g.vm_packing()
-    p2, dl2, ic2, dg2 = g2.vm_packing()
-    assert p1.n_blocks_out == p2.n_blocks_out
-    for a, b in [
-        (p1.src, p2.src), (p1.dst_local, p2.dst_local), (p1.meta, p2.meta),
-        (p1.pad_mask, p2.pad_mask), (p1.order, p2.order),
-        (np.asarray(dl1), np.asarray(dl2)),
-        (np.asarray(ic1), np.asarray(ic2)), (dg1, dg2),
-    ]:
-        assert np.array_equal(np.asarray(a), np.asarray(b))
+    for a, b in zip(g.in_edge_order, g2.in_edge_order):
+        assert np.array_equal(a, b)
     for ex, q in queries:
         assert np.array_equal(ex.traversals(q), QueryExecutor(g).traversals(q))
 
@@ -42,7 +34,7 @@ def _assert_full_parity(g: LabelledGraph, queries=()):
 def _seed_caches(g: LabelledGraph):
     g.reverse_edge_index
     g.cached_neighbor_label_counts()
-    g.vm_packing()
+    g.in_edge_order
 
 
 # ---------------------------------------------------------------------------
@@ -124,15 +116,30 @@ def test_duplicate_and_self_loop_additions_dropped(paper_graph):
     _assert_full_parity(g)
 
 
-def test_stale_vm_packing_not_served(paper_graph):
-    """Stale derived caches must be refreshed, not silently reused."""
+def test_stale_dst_order_not_served(paper_graph, paper_trie,
+                                   paper_partition):
+    """Stale derived caches must be refreshed, not silently reused: after a
+    mutation the destination order is recomputed and the jnp field's device
+    copy of it re-uploads."""
+    from repro.core.visitor import extroversion_field
+
     g = paper_graph.subgraph_mask(np.ones(6, bool))
     _seed_caches(g)
-    before = g.vm_packing()
+    arrays = paper_trie.compile(g.label_names)
+    part = np.asarray(paper_partition)
+    pre = {}
+    extroversion_field(g, arrays, part, 2, _precomputed=pre)
+    before = pre["_dev"]["dst_order"]
     g.apply_mutations(MutationBatch(add_edges=[(0, 5)]))
-    after = g.vm_packing()
-    assert after[0].src.shape != before[0].src.shape or not np.array_equal(
-        np.asarray(after[0].src), np.asarray(before[0].src))
+    perm, in_ptr = g.in_edge_order
+    assert perm.shape == (g.m,)
+    fld = extroversion_field(g, arrays, part, 2, _precomputed=pre)
+    after = pre["_dev"]["dst_order"]
+    assert after is not before
+    assert np.array_equal(np.asarray(after["src"]), g.src[perm])
+    assert np.array_equal(np.asarray(after["in_ptr"]), in_ptr)
+    fresh = extroversion_field(_rebuilt(g), arrays, part, 2)
+    assert np.array_equal(fld.edge_mass, fresh.edge_mass)
 
 
 # ---------------------------------------------------------------------------

@@ -105,7 +105,7 @@ def test_backend_fallback_and_probe_recovery():
     fi = FaultInjector()
     loop = ServingLoop(
         g, 4, taper_config=TaperConfig(max_iterations=2,
-                                       field_backend="pallas"),
+                                       field_backend="pallas_sharded"),
         policy=_eager_policy(),
         config=ServeLoopConfig(
             micro_batch=4, overlap_invocations=False, faults=fi,
@@ -122,21 +122,47 @@ def test_backend_fallback_and_probe_recovery():
             pass
     served_during_faults = loop.metrics.completed
     assert served_during_faults >= 4
-    # 4 consecutive failures at threshold 2: pallas -> jnp, then pinned at
-    # the bottom rung (no further fallback to record)
+    # 4 consecutive failures at threshold 2: pallas_sharded -> jnp, then
+    # pinned at the bottom rung (no further fallback to record)
     s = loop.stats()
     assert s["field_backend"] == "jnp"
     assert s["backend_fallbacks"] == 1
     assert s["degraded"] == 1 and s["healthy"] == 0
     assert loop.metrics.invocation_failures == 4
-    # healthy commits at probe_after=1 walk back up: jnp -> pallas
+    # healthy commits at probe_after=1 walk back up: jnp -> pallas_sharded
     while loop.stats()["backend_recoveries"] < 1:
         loop.submit(MQ1)
         loop.pump()
     s = loop.stats()
-    assert s["field_backend"] == "pallas"
+    assert s["field_backend"] == "pallas_sharded"
     assert s["degraded"] == 0 and s["healthy"] == 1
     assert s["completed"] >= served_during_faults + 1
+
+
+def test_jnp_is_the_bottom_rung():
+    """A store that starts on the one-device program has nowhere to fall:
+    repeated invocation failures record no fallback and keep ``jnp``."""
+    pytest.importorskip("jax")
+    g = musicbrainz_like(300, seed=21)
+    fi = FaultInjector()
+    loop = ServingLoop(
+        g, 4, taper_config=TaperConfig(max_iterations=2, field_backend="jnp"),
+        policy=_eager_policy(),
+        config=ServeLoopConfig(
+            micro_batch=4, overlap_invocations=False, faults=fi,
+            invocation_retry_backoff_s=0.0, backend_fallback_after=2))
+    fi.arm(SITE_INVOCATION, times=4)
+    while fi.fired_total() < 4:
+        loop.submit(MQ1)
+        try:
+            loop.pump()
+        except InjectedFault:
+            pass
+    s = loop.stats()
+    assert loop.metrics.invocation_failures == 4
+    assert s["backend_fallbacks"] == 0
+    assert s["field_backend"] == "jnp"
+    assert s["degraded"] == 0
 
 
 def test_invocation_failure_sets_retry_backoff():
@@ -331,7 +357,7 @@ def test_shard_upload_fault_survivable_then_degrades():
     s = loop.stats()
     assert s["upload_failures"] == 2
     assert s["backend_fallbacks"] == 1
-    assert s["field_backend"] == "pallas" and s["degraded"] == 1
+    assert s["field_backend"] == "jnp" and s["degraded"] == 1
     loop.submit(MQ1)
     assert loop.pump() == 1                # still answering queries
     loop.stop()

@@ -1,7 +1,7 @@
 """Hypothesis property test: after any random MutationBatch sequence the
-incrementally-patched caches (reverse_edge_index, neighbour-label counts,
-vm_packing, executor traversal counts) are bit-identical to rebuilding from
-scratch.  The seeded numpy twin (always runnable) lives in
+graph's derived caches (reverse_edge_index and neighbour-label counts,
+patched; in_edge_order; executor traversal counts) are bit-identical to
+rebuilding from scratch.  The seeded numpy twin (always runnable) lives in
 tests/test_dynamic_graph.py."""
 import numpy as np
 import pytest

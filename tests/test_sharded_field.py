@@ -154,15 +154,6 @@ def test_sharded_field_parity_depth_cap():
         _assert_field_parity(ref, sh)
 
 
-def test_sharded_field_parity_vs_pallas_single_device():
-    g = musicbrainz_like(900, seed=13)
-    arrays = _trie(g)
-    part = hash_partition(g.n, 8, seed=3)
-    ref = extroversion_field(g, arrays, part, 8, backend="pallas")
-    sh = extroversion_field(g, arrays, part, 8, backend="pallas_sharded")
-    _assert_field_parity(ref, sh)
-
-
 # ---------------------------------------------------------------------------
 # topology-aware shard maps + exchange backends (PR 5)
 # ---------------------------------------------------------------------------

@@ -1,4 +1,4 @@
-"""Parity suite: the vectorised swap engine and Pallas field vs their seeds.
+"""Parity suite: the vectorised swap engine and the sharded field vs their seeds.
 
 The vectorised ``swap_iteration`` must produce *bit-identical*
 partitions and stats to the seed per-vertex implementation
@@ -6,8 +6,9 @@ partitions and stats to the seed per-vertex implementation
 offer/receive decisions, same rejected-offer counts — across random labelled
 graphs, both ``ext_to`` modes, and chained iterations.
 
-The Pallas-backed extroversion field is held to numerical (not bit) parity
-with the fused jnp oracle: same DP, different op order.
+The ``vm_step`` kernel field (``backend="pallas_sharded"``, here on a mesh
+of one device) is held to numerical (not bit) parity with the fused jnp
+program: same DP, different op order.
 """
 import dataclasses
 
@@ -24,6 +25,7 @@ from repro.core.tpstry import TPSTry
 from repro.core.visitor import extroversion_field
 from repro.graphs.generators import musicbrainz_like, provgen_like
 from repro.graphs.partition import hash_partition
+from repro.launch.mesh import make_smoke_mesh
 
 CASES = [
     # (seed, generator, queries, k)
@@ -175,57 +177,60 @@ def test_reverse_edge_index_is_involution():
     assert (rev[rev] == np.arange(g.m)).all()
 
 
+def _one_device_sharded(g, arrays, part, k, **kw):
+    """The ``vm_step`` kernel program on a mesh of one device."""
+    return extroversion_field(g, arrays, part, k, backend="pallas_sharded",
+                              _precomputed={"_mesh": make_smoke_mesh(1)},
+                              **kw)
+
+
 @pytest.mark.parametrize("dense", [True, False], ids=["dense", "two-phase"])
-def test_pallas_field_matches_jnp(dense):
+def test_sharded_field_on_one_device_mesh_matches_jnp(dense):
     g, arrays, part = _setup(9, provgen_like,
                              ["Entity.Entity.Entity", "Agent.Activity.Entity"],
                              4, n=800)
     f_jnp = extroversion_field(g, arrays, part, 4, dense_ext_to=dense,
                                backend="jnp")
-    f_pal = extroversion_field(g, arrays, part, 4, dense_ext_to=dense,
-                               backend="pallas")
-    np.testing.assert_allclose(f_pal.alpha, f_jnp.alpha, rtol=1e-4, atol=1e-6)
-    np.testing.assert_allclose(f_pal.edge_mass, f_jnp.edge_mass,
+    f_sh = _one_device_sharded(g, arrays, part, 4, dense_ext_to=dense)
+    np.testing.assert_allclose(f_sh.alpha, f_jnp.alpha, rtol=1e-4, atol=1e-6)
+    np.testing.assert_allclose(f_sh.edge_mass, f_jnp.edge_mass,
                                rtol=1e-4, atol=1e-6)
-    np.testing.assert_allclose(f_pal.pr, f_jnp.pr, rtol=1e-4, atol=1e-6)
-    np.testing.assert_allclose(f_pal.extro_mass, f_jnp.extro_mass,
+    np.testing.assert_allclose(f_sh.pr, f_jnp.pr, rtol=1e-4, atol=1e-6)
+    np.testing.assert_allclose(f_sh.extro_mass, f_jnp.extro_mass,
                                rtol=1e-4, atol=1e-6)
-    np.testing.assert_allclose(f_pal.extroversion, f_jnp.extroversion,
+    np.testing.assert_allclose(f_sh.extroversion, f_jnp.extroversion,
                                rtol=1e-3, atol=1e-6)
     if dense:
-        np.testing.assert_allclose(f_pal.ext_to, f_jnp.ext_to,
+        np.testing.assert_allclose(f_sh.ext_to, f_jnp.ext_to,
                                    rtol=1e-4, atol=1e-6)
     else:
-        assert f_pal.ext_to is None and f_jnp.ext_to is None
-    assert f_pal.total_extroversion == pytest.approx(
+        assert f_sh.ext_to is None and f_jnp.ext_to is None
+    assert f_sh.total_extroversion == pytest.approx(
         f_jnp.total_extroversion, rel=1e-4, abs=1e-6)
 
 
-def test_pallas_field_depth_cap():
+def test_sharded_field_on_one_device_mesh_depth_cap():
     g, arrays, part = _setup(2, provgen_like, ["Entity.Entity.Entity"], 3,
                              n=500)
     f_jnp = extroversion_field(g, arrays, part, 3, depth_cap=2, backend="jnp")
-    f_pal = extroversion_field(g, arrays, part, 3, depth_cap=2,
-                               backend="pallas")
-    np.testing.assert_allclose(f_pal.edge_mass, f_jnp.edge_mass,
+    f_sh = _one_device_sharded(g, arrays, part, 3, depth_cap=2)
+    np.testing.assert_allclose(f_sh.edge_mass, f_jnp.edge_mass,
                                rtol=1e-4, atol=1e-6)
-    np.testing.assert_allclose(f_pal.pr, f_jnp.pr, rtol=1e-4, atol=1e-6)
+    np.testing.assert_allclose(f_sh.pr, f_jnp.pr, rtol=1e-4, atol=1e-6)
 
 
-def test_taper_invoke_pallas_backend():
-    """A full invocation through the Pallas field backend still improves the
-    objective and keeps balance."""
-    g = provgen_like(800, avg_degree=4.0, seed=4)
-    k = 3
-    w = [(parse_rpq("Entity.Entity.Entity"), 0.6),
-         (parse_rpq("Entity.Activity.Agent"), 0.4)]
-    part0 = hash_partition(g.n, k, seed=1)
-    taper = Taper(g, k, TaperConfig(max_iterations=3, seed=0,
-                                    field_backend="pallas"))
-    report = taper.invoke(part0, w)
-    assert report.objective[-1] <= report.objective[0]
-    p = report.final_part
-    assert p.shape == (g.n,) and p.min() >= 0 and p.max() < k
+@pytest.mark.parametrize("where", ["extroversion_field", "TaperConfig"])
+def test_unknown_field_backend_rejected(where):
+    """Only ``jnp`` and ``pallas_sharded`` exist: any other backend name,
+    ``pallas`` among them, fails where it is named — at the field call, or
+    when the Taper is built, not at its first field evaluation."""
+    removed = 'pallas'
+    g, arrays, part = _setup(2, provgen_like, ["Entity.Entity"], 2, n=200)
+    with pytest.raises(ValueError, match=f"unknown field backend {removed!r}"):
+        if where == "extroversion_field":
+            extroversion_field(g, arrays, part, 2, backend=removed)
+        else:
+            Taper(g, 2, TaperConfig(field_backend=removed))
 
 
 def test_taper_field_lazy_reuse_on_unchanged_trie():

@@ -91,8 +91,7 @@ def test_fused_jnp_field_compiles_at_provgen_1m(one_chip, pq_trie):
     from repro.core.visitor import _build_field_fn
 
     t = pq_trie
-    fn = _build_field_fn(None, t, K, t.max_depth, fused=True,
-                         dense_ext_to=False)
+    fn = _build_field_fn(None, t, K, t.max_depth, dense_ext_to=False)
     L, N = t.n_labels, t.n_nodes
     s = lambda shape, dt: _spec(shape, dt, one_chip)
     edges, ptr = s((M_E,), jnp.int32), s((N_V + 1,), jnp.int32)

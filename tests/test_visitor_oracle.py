@@ -106,7 +106,7 @@ def test_mass_conservation(paper_graph, arrays, paper_partition, field):
 
 
 # ---------------------------------------------------------------------------
-# the fused program's destination-segment sums, against float64 and naive
+# the fused program's destination-segment sums, against the float64 recurrence
 # ---------------------------------------------------------------------------
 
 SEG_LABELS = ["L0", "L1", "L2"]
@@ -206,6 +206,8 @@ def _mutated_hub_graph(pre, t, part, k):
 
 @pytest.mark.parametrize("case", ["symmetric", "one_directional", "mutated"])
 def test_fused_field_matches_float64_and_naive(case):
+    """The fused program against :func:`_field_f64`, the naive
+    transcription of the recurrence (one pass per trie node) in float64."""
     from repro.graphs.partition import hash_partition
 
     k = 4
@@ -228,12 +230,9 @@ def test_fused_field_matches_float64_and_naive(case):
         # the version bump re-uploaded the order and its offsets
         assert pre["_dev"]["dst_order"] is not old
         assert pre["_dev_version"] == g.version
-    naive = extroversion_field(g, t, part, k, fused=False)
     want = _field_f64(g, t, part, k)
     for name, ref in want.items():
-        got = getattr(fused, name)
-        assert _normwise(got, ref) < 1e-5, name
-        assert _normwise(got, getattr(naive, name)) < 1e-5, name
+        assert _normwise(getattr(fused, name), ref) < 1e-5, name
 
 
 @pytest.mark.parametrize("edges", [[], [[0, 1]], [[0, 1], [0, 2], [0, 3]]])
@@ -276,8 +275,7 @@ def test_fused_field_depth_steps_have_no_scatter():
     g = _hub_graph(directed=False)
     t = _seg_trie(g)
     n, m, L, N = g.n, g.m, g.n_labels, t.n_nodes
-    fn = _build_field_fn(None, t, 4, t.max_depth, fused=True,
-                         dense_ext_to=True)
+    fn = _build_field_fn(None, t, 4, t.max_depth, dense_ext_to=True)
     i32 = lambda *shape: jnp.zeros(shape, jnp.int32)
     f32 = lambda *shape: jnp.zeros(shape, jnp.float32)
     hlo = fn.lower(i32(m), i32(m), i32(n), i32(n, L), i32(L), i32(n),
